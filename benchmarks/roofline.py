@@ -201,17 +201,19 @@ def reduce_program_table(shapes=((512, 128, 64), (512, 128, 1024))):
         double-buffering the gather against the carry update is worth
         when the stages are balanced (the JugglePAC overlap, at block
         granularity);
-      * ``contrib`` — the planned gather form; at large ``num_segments``
-        the integer tiers switch to the lane-parallel scatter because
-        the one-hot dot's B*S*W flops would make the *memory-bound*
-        stage compute-bound.
+      * ``contrib`` — the gather form planned for the TPU kernel: the
+        one-hot dot at every ``num_segments`` (the compiled kernel has no
+        scatter-add), so at large S the dot's B*S*W flops show up as a
+        compute-bound gather stage.
 
     Pure analysis — no arrays move; safe in any CI job.  The smoke
     harness (benchmarks/run.py --smoke) writes this table to
     ``experiments/roofline/reduce_smoke.json``.
     """
-    from repro.reduce import get_policy, plan_program
+    from repro.reduce import get_backend, get_policy, plan_program
     from repro.reduce.policy import POLICIES
+
+    kernel = get_backend("pallas")
 
     rows = []
     for block_size, d, s in shapes:
@@ -219,7 +221,8 @@ def reduce_program_table(shapes=((512, 128, 64), (512, 128, 1024))):
             pol = get_policy(name)
             w = pol.domain_width(d)
             prog = plan_program(pol, num_segments=s, domain_width=w,
-                                block_size=block_size)
+                                block_size=block_size,
+                                plans_lanes=kernel.plans_lanes)
             stages = {}
             for st in prog.stages:
                 stages[st.name] = {

@@ -47,6 +47,7 @@ import sys
 from pathlib import Path
 
 from benchmarks import paper_tables
+from repro.launch.compile_cache import enable_compile_cache
 
 BASELINE_PATH = Path(__file__).resolve().parent / "baseline.json"
 #: fail threshold for machine-independent rows: >20% worse than baseline
@@ -160,6 +161,7 @@ def main(argv=None) -> None:
                     help="fail (exit 1) on a >20% regression vs the "
                          "tracked benchmarks/baseline.json")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if (args.write_baseline or args.check_baseline) and not args.smoke:
         ap.error("--write-baseline/--check-baseline track the --smoke "
                  "subset; pass --smoke too")

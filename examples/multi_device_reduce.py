@@ -86,12 +86,14 @@ def main():
 
     # auto-selection: an active multi-device mesh is enough — no backend
     # argument, no mesh argument
-    with Mesh(np.asarray(devs), ("shards",)):
+    with jax.set_mesh(Mesh(np.asarray(devs), ("shards",))):
+        assert R.select_backend(
+            R.get_policy("procrastinate")).name == "shard_map"
         auto = np.asarray(repro.reduce(vals, segment_ids=ids,
                                        num_segments=s,
                                        policy="procrastinate"))
     assert np.array_equal(auto, base["procrastinate"])
-    print("\nauto-selection under `with mesh:` picked shard_map and "
+    print("\nauto-selection under `jax.set_mesh` picked shard_map and "
           "reproduced the single-device bits — scaling out is a context "
           "manager, not a rewrite")
 

@@ -55,7 +55,6 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro import reduce as _reduce
 from repro.models import loss_fn
@@ -136,10 +135,10 @@ def make_shardmap_train_step(cfg: ModelConfig, mesh, *, lr_fn: Callable,
 
     pspec = P()           # params replicated (pure DP; FSDP stays on pjit)
     bspec = P(axes if len(axes) > 1 else axes[0])
-    return shard_map(step, mesh=mesh,
-                     in_specs=(pspec, pspec, pspec, bspec),
-                     out_specs=(pspec, pspec, pspec, pspec),
-                     check_rep=False)
+    return jax.shard_map(step, mesh=mesh,
+                         in_specs=(pspec, pspec, pspec, bspec),
+                         out_specs=(pspec, pspec, pspec, pspec),
+                         check_vma=False)
 
 
 def make_elastic_train_step(cfg: ModelConfig, mesh, *, lr_fn: Callable,
@@ -220,10 +219,10 @@ def make_elastic_train_step(cfg: ModelConfig, mesh, *, lr_fn: Callable,
 
     pspec = P()           # params replicated (pure DP)
     bspec = P(axes if len(axes) > 1 else axes[0])
-    return shard_map(step, mesh=mesh,
-                     in_specs=(pspec, pspec, bspec),
-                     out_specs=(pspec, pspec, pspec),
-                     check_rep=False)
+    return jax.shard_map(step, mesh=mesh,
+                         in_specs=(pspec, pspec, bspec),
+                         out_specs=(pspec, pspec, pspec),
+                         check_vma=False)
 
 
 def init_residuals(params):

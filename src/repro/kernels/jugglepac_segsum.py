@@ -14,8 +14,10 @@ The circuit's streaming schedule, mapped to the TPU grid:
     overlaps the compute stage of the current one (the JugglePAC overlap,
     in-kernel);
   * FSM state 1 (pair raw inputs)       ->  the intra-tile reduction — the
-    staged program's contrib stage: the one-hot MXU matmul, or the
-    PhasedAccu lane-parallel scatter when the program plans it;
+    staged program's contrib stage: the one-hot MXU matmul (f32 at full
+    precision for the float tiers, exact bf16 planes for the integer
+    tiers — see ``repro.reduce.policy.int_plane_dot``); the PhasedAccu
+    lane-parallel scatter runs only under interpret mode;
   * the PIS register file               ->  the policy's carry tuple — (S, D)
     tiles resident in VMEM across grid steps (same output block revisited),
     addressed by segment label exactly like the PIS registers are addressed
@@ -76,15 +78,16 @@ def blocks_per_step_for(block_rows: int, width: int) -> int:
 
 def _segsum_policy_kernel(ids_ref, vals_ref, *out_refs, num_segments: int,
                           seg_offset: int, policy, program,
-                          block_rows: int, blocks_per_step: int):
+                          block_rows: int, blocks_per_step: int,
+                          interpret: bool):
     """The streaming schedule with the accuracy-policy carry baked in.
 
-    The staged contrib (``block_contrib`` — dot or lane form per the
-    planned program) and ``policy.update`` are traced straight into the
-    grid loop — the one canonical op sequence per (policy, program); the
-    cross-backend bitwise contract depends on these being the very
-    functions the blocked/ref backends call.  Policies executed here must
-    zero-init their carry.
+    The staged contrib (``block_contrib`` — the dot form; the lane form
+    only under interpret mode) and ``policy.update`` are traced straight
+    into the grid loop — the one canonical op sequence per (policy,
+    program); the cross-backend bitwise contract depends on these being
+    the very functions the blocked/ref backends call.  Policies executed
+    here must zero-init their carry.
 
     The body is software-pipelined over the supertile's blocks: tile j+1
     loads from the VMEM supertile before ``update`` folds tile j, telling
@@ -112,11 +115,14 @@ def _segsum_policy_kernel(ids_ref, vals_ref, *out_refs, num_segments: int,
                                 num_segments, policy, program,
                                 seg_offset=seg_offset)
         carry = policy.update(carry, contrib)
-        # pin the fold boundary: with the supertile loop unrolled into one
-        # computation, XLA may fuse consecutive float folds into a single
-        # larger reduction (at S=1 the one-hot dot degenerates to a plain
-        # reduce), silently changing the addition order the program fixes
-        carry = jax.lax.optimization_barrier(carry)
+        if interpret:
+            # pin the fold boundary: interpret mode runs the unrolled
+            # supertile loop as one XLA computation, which may fuse
+            # consecutive float folds into a single larger reduction (at
+            # S=1 the one-hot dot degenerates to a plain reduce), silently
+            # changing the addition order the program fixes.  Mosaic keeps
+            # the traced order and has no lowering for the barrier.
+            carry = jax.lax.optimization_barrier(carry)
     for r, c in zip(out_refs, carry):
         r[...] = c
 
@@ -142,8 +148,15 @@ def segsum_policy_pallas(values: jnp.ndarray, segment_ids: jnp.ndarray,
 
     ``program`` is a planned ``BlockProgram`` (contrib mode);
     ``blocks_per_step=None`` sizes the supertile from the VMEM window
-    (``blocks_per_step_for``).
+    (``blocks_per_step_for``).  The compiled kernel runs only the dot
+    form: Mosaic has no scatter-add lowering, so a ``"lanes"`` program
+    is refused unless ``interpret=True``.
     """
+    if not interpret and program is not None and program.contrib == "lanes":
+        raise ValueError(
+            "segsum_policy_pallas: the compiled kernel runs only the "
+            "one-hot dot contrib (Mosaic has no scatter-add); plan "
+            "contrib='dot', or use backend='blocked' for the lane form")
     n, d = values.shape
     if n % block_rows:
         raise ValueError(f"segsum_policy_pallas: N={n} must be a multiple "
@@ -163,7 +176,7 @@ def segsum_policy_pallas(values: jnp.ndarray, segment_ids: jnp.ndarray,
                                num_segments=num_segments,
                                seg_offset=seg_offset, policy=policy,
                                program=program, block_rows=block_rows,
-                               blocks_per_step=bps)
+                               blocks_per_step=bps, interpret=interpret)
     # the policy's init is the one source of truth for per-component carry
     # shapes/dtypes (exact2 mixes int32 limbs with f32 residuals, and its
     # carries are half the domain width); the zeros are traced away

@@ -21,15 +21,12 @@ import jax
 import jax.numpy as jnp
 
 from repro.reduce.backends import OUT_OF_RANGE_LABEL
+from repro.reduce.backends import interpret_default as _interpret_default
 from repro.reduce.policy import get_policy
 
 from . import flash_decode as _fd
 from . import intac_accum as _ia
 from . import jugglepac_segsum as _ss
-
-
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 # VMEM budget the segsum accumulator tile may claim (floats).

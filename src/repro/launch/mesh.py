@@ -11,24 +11,19 @@ Importing this module never touches jax device state; call the function.
 from __future__ import annotations
 
 import jax
-
-try:                                  # jax >= 0.4.38
-    from jax.sharding import AxisType
-    _AXIS_KW = lambda n: {"axis_types": (AxisType.Auto,) * n}  # noqa: E731
-except ImportError:                   # older jax: Auto is the only mode
-    _AXIS_KW = lambda n: {}                                    # noqa: E731
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_AXIS_KW(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_mesh(shape, axes):
     """Arbitrary mesh for tests/smoke (e.g. (1, 1) on one CPU device)."""
     return jax.make_mesh(tuple(shape), tuple(axes),
-                         **_AXIS_KW(len(shape)))
+                         axis_types=(AxisType.Auto,) * len(shape))
 
 
 def dp_axes(mesh) -> tuple:

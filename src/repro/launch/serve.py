@@ -18,6 +18,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config, get_smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params
 from repro.serve.engine import Engine, Request
 
@@ -35,6 +36,7 @@ def main(argv=None):
                          "0 = submit everything at time zero")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = (get_smoke_config if args.smoke else get_config)(args.arch)
     if cfg.embed_inputs or cfg.is_encdec:

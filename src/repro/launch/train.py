@@ -41,6 +41,7 @@ from repro.data.pipeline import DataCfg, make_source
 from repro.distributed.collectives import (init_residuals,
                                            make_shardmap_train_step)
 from repro.launch.mesh import make_mesh
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params
 from repro.optim import adamw
 from repro.train.steps import make_train_step
@@ -69,6 +70,7 @@ def main(argv=None):
     ap.add_argument("--simulate-failure-at", type=int, default=0)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = (get_smoke_config if args.smoke else get_config)(args.arch)
     key = jax.random.PRNGKey(args.seed)

@@ -409,14 +409,13 @@ def merge_across(acc: Accumulator, state, axis_names):
 
     >>> import jax, jax.numpy as jnp, numpy as np
     >>> from jax.sharding import Mesh, PartitionSpec as P
-    >>> from jax.experimental.shard_map import shard_map
     >>> mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
     >>> acc = KahanAccumulator()
     >>> def f(x):
     ...     st = acc.push(acc.init(x), x)          # local partial stream
     ...     return acc.finalize(merge_across(acc, st, ("data",)))
-    >>> out = shard_map(f, mesh=mesh, in_specs=P(), out_specs=P(),
-    ...                 check_rep=False)(jnp.asarray([2.0, 3.0]))
+    >>> out = jax.shard_map(f, mesh=mesh, in_specs=P(), out_specs=P(),
+    ...                     check_vma=False)(jnp.asarray([2.0, 3.0]))
     >>> [float(v) for v in out]
     [2.0, 3.0]
     """

@@ -34,7 +34,8 @@ import jax.numpy as jnp
 from ..core import intac
 from .algebra import get_op
 from .backends import (OUT_OF_RANGE_LABEL, ambient_mesh, default_mesh,
-                       get_backend, mask_out_of_range, select_backend)
+                       get_backend, mask_out_of_range, select_backend,
+                       select_local_backend)
 from .policy import get_policy
 from .program import plan_program
 
@@ -144,7 +145,7 @@ def _dispatch(values, segment_ids, *, spec: ReduceSpec, num_segments: int,
     # ``reduce`` resolved backend=None before the jit boundary, so specs
     # arriving here are concrete; keep the fallback for direct callers.
     backend = (get_backend(spec.backend) if spec.backend is not None
-               else select_backend(policy))
+               else select_backend(policy, traced=True))
     if not backend.supports(policy):
         raise ValueError(f"backend {backend.name!r} does not implement "
                          f"policy {policy.name!r} "
@@ -187,12 +188,16 @@ def _dispatch(values, segment_ids, *, spec: ReduceSpec, num_segments: int,
             # plan the staged block-program once, above the executor: the
             # contrib mode (one-hot dot vs lane-parallel scatter) and the
             # stage cost hints are a (policy, shape) decision, not a
-            # backend one
+            # backend one, bar whether the executor that runs the
+            # blocks (each shard's local one, under shard_map) can
+            # scatter at all
+            executor = (select_local_backend(policy)
+                        if backend.distributed else backend)
             run_kw["program"] = plan_program(
                 policy, num_segments=num_segments,
                 domain_width=policy.domain_width(d),
                 block_size=spec.block_size, contrib=spec.contrib,
-                op=spec.op)
+                op=spec.op, plans_lanes=executor.plans_lanes)
         if backend.staged and backend.distributed:
             # the staged distributed path: compute only the global
             # statistic here (one max-reduce), hand the *raw* rows to the
@@ -382,10 +387,10 @@ def reduce(values, *, segment_ids=None, num_segments: Optional[int] = None,
       coeffs: ascending polynomial coefficients for coefficient-taking
         ops (``op="poly"``); static — becomes ``ReduceSpec.coeffs``.
       mesh: the device mesh for a distributed backend; None uses the
-        ambient ``with mesh:`` context, else one flat axis over every
-        visible device.  Rejected for single-device backends.  Note the
-        ambient mesh only steers *auto-selection* for top-level (eager)
-        calls — inside jit/shard_map-traced code pass ``mesh=`` (or
+        ambient ``with jax.set_mesh(mesh):`` context, else one flat axis
+        over every visible device.  Rejected for single-device backends.
+        Note the ambient mesh only steers *auto-selection* for calls on
+        concrete arrays — on traced values pass ``mesh=`` (or
         ``backend="shard_map"``) explicitly; see ``select_backend``.
       axis_names: mesh axes to shard the stream over (default: all of
         the mesh's axes); only meaningful with a distributed backend.
@@ -450,7 +455,9 @@ def reduce(values, *, segment_ids=None, num_segments: Optional[int] = None,
     # cached executor choice.
     pol = get_policy(spec.policy)
     auto = spec.backend is None
-    bk = (select_backend(pol, mesh=mesh) if auto
+    traced = any(isinstance(x, jax.core.Tracer)
+                 for x in (values, segment_ids, weights))
+    bk = (select_backend(pol, mesh=mesh, traced=traced) if auto
           else get_backend(spec.backend))
     spec = spec if spec.backend == bk.name else spec.replace(backend=bk.name)
     if bk.distributed:

@@ -81,13 +81,17 @@ class Backend:
     #: domain map runs per shard.  Off by default so pre-staged custom
     #: backends keep their old ``run`` signature.
     staged: bool = False
+    #: whether ``contrib="auto"`` may plan the lane-parallel scatter form
+    #: for this executor (the compiled pallas kernel runs only the dot)
+    plans_lanes: bool = True
 
     def supports(self, policy: Policy) -> bool:
         return "*" in self.policies or policy.name in self.policies
 
 
 def register_backend(name: str, *, policies, description: str = "",
-                     distributed: bool = False, staged: bool = False):
+                     distributed: bool = False, staged: bool = False,
+                     plans_lanes: bool = True):
     """Decorator: register ``fn`` as backend ``name``.
 
     ``policies``: iterable of policy names the executor implements, or the
@@ -120,7 +124,8 @@ def register_backend(name: str, *, policies, description: str = "",
             caps = frozenset(policies)
         BACKENDS[name] = Backend(name=name, run=fn, policies=caps,
                                  description=description,
-                                 distributed=distributed, staged=staged)
+                                 distributed=distributed, staged=staged,
+                                 plans_lanes=plans_lanes)
         return fn
     return deco
 
@@ -134,27 +139,20 @@ def get_backend(name: str) -> Backend:
 
 
 def ambient_mesh() -> Optional[Mesh]:
-    """The mesh of an enclosing ``with mesh:`` context, or None.
+    """The mesh of an enclosing ``with jax.set_mesh(mesh):`` context, or
+    None.
 
     The ``shard_map`` backend and ``select_backend`` both consult this so
     ``repro.reduce(...)`` scales out without explicit plumbing whenever the
     caller already activated a mesh.  Resolution happens *before* the jit
     boundary (in ``reduce``), so the dispatch cache keys on the concrete
-    mesh, never on mutable thread state.
+    mesh, never on mutable thread state.  JAX hands out the concrete mesh
+    only outside traced code: inside ``jit`` under ``jax.set_mesh`` this
+    raises, and the caller passes ``mesh=`` instead.
     """
-    try:
-        from jax._src import mesh as _mesh_lib      # no public accessor yet
-        m = _mesh_lib.thread_resources.env.physical_mesh
-        return None if m.empty else m
-    except (ImportError, AttributeError):           # jax internals moved
-        # degrade loudly, not silently: `with mesh:` auto-selection stops
-        # working until this accessor is updated (tests pin the behavior)
-        import warnings
-        warnings.warn("repro.reduce: cannot read the ambient jax mesh "
-                      "from this jax version; `with mesh:` backend "
-                      "auto-selection is disabled — pass mesh= explicitly",
-                      RuntimeWarning, stacklevel=2)
+    if jax.sharding.get_abstract_mesh().empty:
         return None
+    return jax.sharding.get_mesh()
 
 
 def default_mesh() -> Mesh:
@@ -162,34 +160,37 @@ def default_mesh() -> Mesh:
     return Mesh(np.asarray(jax.devices()), ("shards",))
 
 
-def select_backend(policy: Policy, mesh: Optional[Mesh] = None) -> Backend:
+def select_backend(policy: Policy, mesh: Optional[Mesh] = None, *,
+                   traced: bool = False) -> Backend:
     """Auto-selection: shard_map under a multi-device mesh, the TPU kernel
     on TPU, the scanned form elsewhere.
 
-    A mesh (explicit, or — for top-level untraced calls only — the
-    ambient ``with mesh:`` context) spanning more than one device selects
-    the ``shard_map`` backend, which shards the stream and runs the local
+    A mesh (explicit, or — unless the call is ``traced`` — the ambient
+    ``jax.set_mesh`` context) spanning more than one device selects the
+    ``shard_map`` backend, which shards the stream and runs the local
     auto-choice per shard.  The pallas wrapper already
     tiles the label space to its VMEM budget, so accumulator size never
     disqualifies it; off-TPU the kernel runs in interpret mode (a
     validation path, not a fast path), so ``blocked`` is the performance
     default.
     """
-    if mesh is None:
-        # Honor the ambient mesh only for top-level (untraced) calls:
+    if mesh is None and not traced:
+        # Honor the ambient mesh only for calls on concrete arrays:
         # reduce() is also called from inside jit/shard_map-traced model
         # code (MoE combine, serving means), where auto-escalating to a
         # nested shard_map would be wrong.  An explicit mesh= always wins.
-        try:
-            clean = jax.core.trace_state_clean()
-        except Exception:
-            clean = False       # can't tell => never auto-escalate
-        mesh = ambient_mesh() if clean else None
+        mesh = ambient_mesh()
     if mesh is not None and mesh.size > 1:
         cand = get_backend("shard_map")
         if cand.supports(policy):
             return cand
     return select_local_backend(policy)
+
+
+def interpret_default() -> bool:
+    """Pallas interpret mode off-TPU; compiled Mosaic kernels on TPU.
+    The one resolution every kernel wrapper shares."""
+    return jax.default_backend() != "tpu"
 
 
 def select_local_backend(policy: Policy) -> Backend:
@@ -281,7 +282,7 @@ def _run_blocked(values, segment_ids, num_segments, *, policy: Policy,
 
 @register_backend("pallas", policies=("fast", "compensated", "exact",
                                       "exact2", "procrastinate"),
-                  staged=True,
+                  staged=True, plans_lanes=False,
                   description="TPU Pallas kernel (interpret off-TPU), "
                               "double-buffered multi-block grid, "
                               "VMEM-budget label-space tiling")
@@ -292,7 +293,7 @@ def _run_pallas(values, segment_ids, num_segments, *, policy: Policy,
     from repro.kernels import jugglepac_segsum as _ss
     from repro.kernels.ops import seg_tile_for
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_default()
     d = values.shape[1]
     # same padding contract as every backend, flattened back for the grid
     vb, ib, _ = _pad_to_blocks(values, segment_ids, block_size)
@@ -364,7 +365,6 @@ def _run_shard_map(values, segment_ids, num_segments, *, policy: Policy,
     """
     # deferred: collective imports this module's sentinel at load time
     from .collective import merge_carry_across
-    from jax.experimental.shard_map import shard_map
     if mesh is None:
         mesh = ambient_mesh() or default_mesh()
     axes = tuple(axis_names) if axis_names else tuple(mesh.axis_names)
@@ -398,9 +398,9 @@ def _run_shard_map(values, segment_ids, num_segments, *, policy: Policy,
         return merge_carry_across(policy, carry, axes)
 
     row_spec = axes if len(axes) > 1 else axes[0]
-    return shard_map(shard_body, mesh=mesh,
-                     in_specs=(P(row_spec, None), P(row_spec))
-                     + (P(),) * len(prep_state),
-                     out_specs=P(), check_rep=False)(
-                         values, segment_ids.astype(jnp.int32),
-                         *prep_state)
+    return jax.shard_map(shard_body, mesh=mesh,
+                         in_specs=(P(row_spec, None), P(row_spec))
+                         + (P(),) * len(prep_state),
+                         out_specs=P(), check_vma=False)(
+                             values, segment_ids.astype(jnp.int32),
+                             *prep_state)

@@ -92,11 +92,10 @@ def collective_mean(x: jnp.ndarray, axis_names: Sequence[str], *,
 
     >>> import jax, jax.numpy as jnp, numpy as np
     >>> from jax.sharding import Mesh, PartitionSpec as P
-    >>> from jax.experimental.shard_map import shard_map
     >>> mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
     >>> f = lambda x: collective_mean(x, ("data",), policy="exact2")[0]
-    >>> out = shard_map(f, mesh=mesh, in_specs=P(), out_specs=P(),
-    ...                 check_rep=False)(jnp.asarray([1.5, -2.0]))
+    >>> out = jax.shard_map(f, mesh=mesh, in_specs=P(), out_specs=P(),
+    ...                     check_vma=False)(jnp.asarray([1.5, -2.0]))
     >>> [float(v) for v in out]
     [1.5, -2.0]
     """
@@ -146,13 +145,12 @@ def collective_weighted_mean(x: jnp.ndarray, w: jnp.ndarray, axis_names,
 
     >>> import jax, jax.numpy as jnp, numpy as np
     >>> from jax.sharding import Mesh, PartitionSpec as P
-    >>> from jax.experimental.shard_map import shard_map
     >>> mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
     >>> f = lambda x, w: collective_weighted_mean(x, w, ("data",),
     ...                                           policy="exact2")
-    >>> out = shard_map(f, mesh=mesh, in_specs=(P(), P()), out_specs=P(),
-    ...                 check_rep=False)(jnp.asarray([1.0, 4.0]),
-    ...                                  jnp.asarray([3.0, 1.0]))
+    >>> out = jax.shard_map(f, mesh=mesh, in_specs=(P(), P()), out_specs=P(),
+    ...                     check_vma=False)(jnp.asarray([1.0, 4.0]),
+    ...                                      jnp.asarray([3.0, 1.0]))
     >>> [float(v) for v in out]                    # per-element w*x / w
     [1.0, 4.0]
     """
@@ -177,11 +175,10 @@ def collective_moments(x: jnp.ndarray, axis_names, *,
 
     >>> import jax, jax.numpy as jnp, numpy as np
     >>> from jax.sharding import Mesh, PartitionSpec as P
-    >>> from jax.experimental.shard_map import shard_map
     >>> mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
     >>> f = lambda x: collective_moments(x, ("data",), policy="exact2")
-    >>> m, v = shard_map(f, mesh=mesh, in_specs=P(), out_specs=(P(), P()),
-    ...                  check_rep=False)(jnp.asarray([1.5, -2.0]))
+    >>> m, v = jax.shard_map(f, mesh=mesh, in_specs=P(), out_specs=(P(), P()),
+    ...                      check_vma=False)(jnp.asarray([1.5, -2.0]))
     >>> [float(a) for a in m], [float(a) for a in v]
     ([1.5, -2.0], [0.0, 0.0])
     """
@@ -217,11 +214,10 @@ def elastic_reduce_mean(stack: jnp.ndarray, axis_names, *,
 
     >>> import jax, jax.numpy as jnp, numpy as np
     >>> from jax.sharding import Mesh, PartitionSpec as P
-    >>> from jax.experimental.shard_map import shard_map
     >>> mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
     >>> f = lambda x: elastic_reduce_mean(x, ("data",))
-    >>> out = shard_map(f, mesh=mesh, in_specs=P("data"), out_specs=P(),
-    ...                 check_rep=False)(jnp.asarray([[1.0, 3.0]]))
+    >>> out = jax.shard_map(f, mesh=mesh, in_specs=P("data"), out_specs=P(),
+    ...                     check_vma=False)(jnp.asarray([[1.0, 3.0]]))
     >>> [float(v) for v in out]
     [1.0, 3.0]
     """

@@ -77,10 +77,9 @@ shape-polymorphic:
   ``contrib(onehot, vals)``           -> the gather stage, dot form: the
                                          (S, W) one-hot matmul(s) mapping
                                          a (B, W) domain block into what
-                                         ``update`` folds (policies with a
-                                         multi-part domain, e.g. exact2's
-                                         quantized + residual halves, run
-                                         one dot per part)
+                                         ``update`` folds (integer domains
+                                         through the exact bf16 plane dot,
+                                         ``int_plane_dot``)
   ``contrib_lanes(ids, vals, S)``     -> the gather stage, lane form:
                                          PhasedAccu-style per-lane
                                          scatter-add partial sums folded
@@ -136,6 +135,57 @@ POLICIES: Dict[str, "Policy"] = {}
 #: lanes the generic lane-parallel contrib splits a block into (the
 #: PhasedAccu phase count; each lane owns a contiguous row slice)
 LANES_DEFAULT = 4
+
+#: bits per plane of the exact integer dot: a plane value |p| <= 2^8 is
+#: exact in bf16, so a one-hot column sum over B rows stays <= B * 2^8
+PLANE_BITS = 8
+#: the longest block the plane dot covers exactly: its f32 column sums
+#: stay <= 2^24, where every integer is representable
+MAX_PLANE_DOT_ROWS = 1 << (24 - PLANE_BITS)
+
+
+def int_plane_dot(onehot: jnp.ndarray, parts) -> jnp.ndarray:
+    """The exact one-hot dot of integer columns through bf16 planes.
+
+    ``onehot`` is the (B, S) boolean one-hot; ``parts`` is a sequence of
+    ``(values, bits)`` with ``values`` a (B, W_i) array of integers and
+    ``|values| <= 2^bits``.  Each part splits into ``ceil(bits / 8)``
+    planes — unsigned low bytes and one signed top plane, all exact in
+    bf16 — and all planes run as one bf16 dot accumulated in f32, whose
+    column sums stay below 2^24 and are therefore exact in any order.
+    The planes then shift-add in int32, which wraps exactly as an int32
+    dot would.  Returns (S, sum W_i) int32, bit for bit the int32 dot on
+    every backend — TPU matrix units take no int32 x int32 product.
+    """
+    b = onehot.shape[0]
+    if b > MAX_PLANE_DOT_ROWS:
+        raise ValueError(f"int_plane_dot: {b}-row blocks exceed the exact "
+                         f"f32 plane-sum bound ({MAX_PLANE_DOT_ROWS} rows)")
+    planes, layout = [], []
+    for vals, bits in parts:
+        v = vals.astype(jnp.int32)
+        n = max(1, -(-int(bits) // PLANE_BITS))
+        for k in range(n):
+            p = jnp.right_shift(v, k * PLANE_BITS)
+            if k + 1 < n:
+                p = jnp.bitwise_and(p, (1 << PLANE_BITS) - 1)
+            planes.append(p.astype(jnp.float32).astype(jnp.bfloat16))
+        layout.append((v.shape[1], n))
+    x = planes[0] if len(planes) == 1 else jnp.concatenate(planes, axis=1)
+    oh = onehot.astype(jnp.float32).astype(jnp.bfloat16).T
+    sums = jnp.dot(oh, x, preferred_element_type=jnp.float32
+                   ).astype(jnp.int32)
+    out, off = [], 0
+    for w, n in layout:
+        acc = sums[:, off:off + w]
+        # detlint: ok[DET002] int32 plane shift-adds: associative, exact
+        # (wrap mod 2^32 like the int32 dot they replace)
+        for k in range(1, n):
+            lo = off + k * w
+            acc = acc + jnp.left_shift(sums[:, lo:lo + w], k * PLANE_BITS)
+        out.append(acc)
+        off += n * w
+    return out[0] if len(out) == 1 else jnp.concatenate(out, axis=1)
 
 
 def fused_psum(arrays, axis_names):
@@ -219,6 +269,9 @@ class Policy:
     carry_len: int = 1
     #: dtype the backends accumulate in (drives kernel specialization)
     acc_dtype = jnp.float32
+    #: integer domains: every domain value satisfies |v| <= 2^domain_bits
+    #: (sizes the planes of the exact dot, ``int_plane_dot``)
+    domain_bits: int = 31
     #: largest schedule block the policy's headroom analysis covers
     #: (None = any); ``reduce`` validates ``block_size`` against it
     max_block_size: Optional[int] = None
@@ -309,9 +362,16 @@ class Policy:
         Every backend (and the pallas kernel body) builds the same boolean
         one-hot and delegates here, so the dot lowering — and with it the
         cross-backend bitwise contract — is defined once, by the policy.
+        Integer domains run the exact bf16 plane dot (``int_plane_dot``,
+        values bounded by ``domain_bits``); float domains an f32 dot at
+        full precision (a TPU's default f32 matmul rounds its inputs to
+        bf16).
         """
+        if jnp.issubdtype(self.acc_dtype, jnp.integer):
+            return int_plane_dot(onehot, [(vals, self.domain_bits)])
         return jnp.dot(onehot.astype(vals.dtype).T, vals,
-                       preferred_element_type=self.acc_dtype)
+                       preferred_element_type=self.acc_dtype,
+                       precision=jax.lax.Precision.HIGHEST)
 
     def contrib_lanes(self, ids: jnp.ndarray, vals: jnp.ndarray,
                       num_segments: int, *, seg_offset: int = 0,
@@ -512,8 +572,8 @@ class Exact2Policy(Policy):
     ``intac.RES_NUM_BINS`` integer digits of the quantum-anchored
     ``intac.RES_BIN_BITS`` superaccumulator window (Neal, arXiv
     1505.05571; the same bin machinery as the procrastinate tier).  All
-    three limbs are then associatively-added int32 state: one int32 dot
-    per block, up to 2^24 rows carry-free, and ``finalize`` is one
+    three limbs are then associatively-added int32 state: one exact
+    plane dot per block, up to 2^24 rows carry-free, and ``finalize`` is one
     ``limbs_resolve3_binned`` — a pure function of the canonical integer
     totals and the scale.
 
@@ -591,16 +651,20 @@ class Exact2Policy(Policy):
         # one (N, (1+NB)*D) f32 domain: quantized part | digit planes.
         # Every column holds an integer below 2^QBITS (q) or 2^6
         # (digits), so the f32 round-trip back to int32 in ``contrib``
-        # is exact and a single int32 dot covers the whole domain.
+        # is exact and a single plane dot covers the whole domain.
         planes = jnp.moveaxis(digits, 0, 1).reshape(
             n, intac.RES_NUM_BINS * d)
         return jnp.concatenate([q.astype(jnp.float32), planes], axis=1)
 
     def contrib(self, onehot: jnp.ndarray, vals: jnp.ndarray):
-        """One int32 dot per block over the whole quantized+digits
-        domain (the same dot lowering on every backend)."""
-        return jnp.dot(onehot.astype(jnp.int32).T, vals.astype(jnp.int32),
-                       preferred_element_type=jnp.int32)
+        """One exact plane dot per block over the whole quantized+digits
+        domain (the same dot lowering on every backend): the quantized
+        columns (|q| <= 2^QBITS) take three planes, the residual digits
+        (|d| <= 2^(RES_BIN_BITS-1)) one."""
+        dd = vals.shape[1] // self._PARTS
+        return int_plane_dot(onehot, [
+            (vals[:, :dd], self.QBITS),
+            (vals[:, dd:], intac.RES_BIN_BITS - 1)])
 
     def init(self, num_segments: int, d: int):
         # d is the (N, (1+NB)*D) domain width: limb carries are (S, D)
@@ -656,6 +720,8 @@ class ProcrastinatePolicy(Policy):
     #: bin digits + the wrap-event overflow counter
     carry_len = 2
     acc_dtype = jnp.int32
+    #: round-to-nearest digits: |d| <= 2^BIN_BITS — one plane
+    domain_bits = intac.BIN_BITS
     max_terms = intac.BIN_MAX_TERMS
     needs_max_stat = True
     #: one wrap_add per bin element + the wrap-event pooling

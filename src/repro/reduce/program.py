@@ -52,6 +52,9 @@ from .policy import LANES_DEFAULT, Policy, get_policy
 #: the conservative side of it.
 LANE_MIN_SEGMENTS = 32
 
+#: fewest label rows the one-hot dot is built with (one f32 sublane tile)
+MIN_LABEL_ROWS = 8
+
 
 @dataclasses.dataclass(frozen=True)
 class BlockStage:
@@ -106,14 +109,17 @@ class BlockProgram:
 
 def plan_program(policy, *, num_segments: int, domain_width: int,
                  block_size: int = 512, contrib: str = "auto",
-                 lanes: int = LANES_DEFAULT, op: str = "sum") -> BlockProgram:
+                 lanes: int = LANES_DEFAULT, op: str = "sum",
+                 plans_lanes: bool = True) -> BlockProgram:
     """Plan the staged block-program for one (policy, shape) pair.
 
     ``contrib="auto"`` applies the cost model: integer-domain policies
     switch to the lane-parallel scatter form once ``num_segments``
     crosses ``LANE_MIN_SEGMENTS`` (where the one-hot dot's B*S*W flops
     make it the slower *and* still memory-bound stage) — a pure
-    performance decision, bitwise-invisible by associativity.  Float
+    performance decision, bitwise-invisible by associativity — unless
+    the executor cannot run the scatter (``plans_lanes=False``: the
+    pallas kernel, whose compiled form has no scatter-add).  Float
     tiers always plan the dot under "auto"; ``contrib="lanes"`` forces
     the lane form anywhere (for float domains that is a documented
     rounding-order change, exactly like the shard_map fast merge).
@@ -133,7 +139,7 @@ def plan_program(policy, *, num_segments: int, domain_width: int,
                          f"got {contrib!r}")
     if contrib == "auto":
         integer_domain = jnp.issubdtype(policy.acc_dtype, jnp.integer)
-        contrib = ("lanes" if integer_domain
+        contrib = ("lanes" if plans_lanes and integer_domain
                    and num_segments >= LANE_MIN_SEGMENTS else "dot")
     costs = policy.stage_costs(block_size, domain_width, num_segments,
                                contrib=contrib)
@@ -165,7 +171,12 @@ def block_contrib(vals, ids, num_segments: int, policy: Policy,
                                     seg_offset=seg_offset,
                                     lanes=program.lanes)
     # broadcasted_iota, not arange: this exact line also runs inside the
-    # pallas kernel body, where 1-D iota does not lower on TPU
-    labels = jax.lax.broadcasted_iota(
-        jnp.int32, (1, num_segments), 1) + seg_offset
-    return policy.contrib(ids[:, None] == labels, vals)
+    # pallas kernel body, where 1-D iota does not lower on TPU.  The label
+    # row is at least one sublane tile (MIN_LABEL_ROWS) tall: a one-label
+    # one-hot degenerates the dot into a vector product, which XLA's CPU
+    # backend emits with context-dependent summation order; the extra
+    # labels' rows are sliced away
+    rows = max(num_segments, MIN_LABEL_ROWS)
+    labels = jax.lax.broadcasted_iota(jnp.int32, (1, rows), 1) + seg_offset
+    out = policy.contrib(ids[:, None] == labels, vals)
+    return out if rows == num_segments else out[:num_segments]

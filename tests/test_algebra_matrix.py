@@ -156,16 +156,15 @@ for op in ("weighted_sum", "sumsq", "moments", "poly"):
 
 # collective companions of the new ops
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 mesh8 = Mesh(np.asarray(jax.devices()), ("data",))
 x8 = jnp.asarray(rng.randn(8, 16).astype(np.float32))
 w8 = jnp.asarray(rng.uniform(0.1, 2.0, (8, 16)).astype(np.float32))
 
 def wmean(xs, ws):
     return R.collective_weighted_mean(xs, ws, ("data",), policy="exact2")
-got = np.asarray(shard_map(wmean, mesh=mesh8,
-                           in_specs=(P("data"), P("data")), out_specs=P(),
-                           check_rep=False)(x8, w8))[0]
+got = np.asarray(jax.shard_map(wmean, mesh=mesh8,
+                               in_specs=(P("data"), P("data")), out_specs=P(),
+                               check_vma=False)(x8, w8))[0]
 xf = np.asarray(x8, np.float64)
 wf = np.asarray(w8, np.float64)
 ref = (xf * wf).sum(0) / wf.sum(0)        # per-element, over the device axis
@@ -173,8 +172,8 @@ print(f"WMEAN {int(np.allclose(got, ref, rtol=1e-4, atol=1e-5))}")
 
 def moms(xs):
     return R.collective_moments(xs, ("data",), policy="exact2")
-m1, var = shard_map(moms, mesh=mesh8, in_specs=P("data"),
-                    out_specs=(P(), P()), check_rep=False)(x8)
+m1, var = jax.shard_map(moms, mesh=mesh8, in_specs=P("data"),
+                        out_specs=(P(), P()), check_vma=False)(x8)
 ok = (np.allclose(np.asarray(m1)[0], xf.mean(0), rtol=1e-4, atol=1e-5)
       and np.allclose(np.asarray(var)[0], xf.var(0), rtol=1e-3, atol=1e-4)
       and (np.asarray(var) >= 0.0).all())

@@ -90,6 +90,35 @@ def test_reduce_rejects_unknown_contrib():
         R.reduce(jnp.ones(8), contrib="scatter")
 
 
+@pytest.mark.parametrize("policy", INT_POLICIES)
+def test_pallas_plans_the_dot_in_and_out_of_interpret_mode(policy):
+    """The compiled kernel has no scatter-add, so auto planning gives the
+    pallas executor the dot form past the crossover too — one behaviour
+    with or without interpret mode — and an explicit lane form sent to
+    the compiled kernel is refused by name."""
+    pol = R.get_policy(policy)
+    pallas = R.get_backend("pallas")
+    prog = plan_program(pol, num_segments=LANE_MIN_SEGMENTS,
+                        domain_width=pol.domain_width(8),
+                        plans_lanes=pallas.plans_lanes)
+    assert prog.contrib == "dot"
+    assert R.get_backend("blocked").plans_lanes
+    with pytest.raises(ValueError, match="one-hot dot"):
+        R.reduce(jnp.ones((512, 8)), segment_ids=jnp.zeros(512, jnp.int32),
+                 num_segments=64, policy=policy, backend="pallas",
+                 contrib="lanes", interpret=False)
+
+
+@pytest.mark.parametrize("policy", POLICIES)
+def test_tpu_auto_selection_is_the_compiled_kernel(policy, monkeypatch):
+    """On a TPU every tier auto-selects the pallas kernel, compiled —
+    no tier is routed to another executor or to interpret mode."""
+    from repro.reduce import backends
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert R.select_backend(R.get_policy(policy)).name == "pallas"
+    assert backends.interpret_default() is False
+
+
 # ---------------------------------------------------------------------------
 # lanes vs dot, per backend
 # ---------------------------------------------------------------------------

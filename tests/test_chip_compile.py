@@ -1,0 +1,78 @@
+"""Compile the reduce kernel for a described TPU v5e, without a chip.
+
+The TPU compiler ships with jaxlib and compiles for a topology that is
+described rather than attached, so these tests refuse — here, at no chip
+time — what Mosaic would refuse on the chip: primitives with no TPU
+lowering (``optimization_barrier``, ``scatter-add``), int32 x int32
+matmuls the v5e MXU does not take, misaligned tiles.  Interpret mode
+cannot see any of those.
+
+The topology is described inside a module fixture, never at import time:
+only one process at a time may load the TPU library, and every test
+worker imports this file.  Keep these tests in this one file.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro import reduce as R
+
+POLICIES = ("fast", "compensated", "exact", "exact2", "procrastinate")
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+def _compiled_text(fn, sharding, *shapes):
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=sharding)
+            for s, dt in shapes]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("num_segments", [8, 64])
+@pytest.mark.parametrize("policy", POLICIES)
+def test_pallas_backend_compiles_for_v5e(one_chip, policy, num_segments):
+    """Every tier, on both sides of the lane-form crossover, compiles to
+    a Mosaic kernel at the width the chip smoke run uses."""
+    n, d = 1 << 16, 128
+
+    def f(v, i):
+        return R.reduce(v, segment_ids=i, num_segments=num_segments,
+                        policy=policy, backend="pallas", interpret=False)
+
+    text = _compiled_text(f, one_chip, ((n, d), jnp.float32),
+                          ((n,), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
+def test_serving_logprob_mean_compiles_for_v5e(one_chip):
+    """The reduction ``Engine`` retires requests through: a segmented
+    compensated mean over a flat (step x slot) logprob stream, D=1."""
+    def f(v, i):
+        return R.reduce(v, segment_ids=i, num_segments=8, op="mean",
+                        policy="compensated", backend="pallas",
+                        interpret=False)
+
+    text = _compiled_text(f, one_chip, ((8 * 33,), jnp.float32),
+                          ((8 * 33,), jnp.int32))
+    assert "tpu_custom_call" in text

@@ -294,7 +294,6 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 from repro import reduce as R
 from repro.testing.faults import drop_shard_carry
 
@@ -314,9 +313,9 @@ def body(v, i):
     carry = drop_shard_carry(carry, "shards", DROP)
     return R.merge_carry_across(pol, carry, ("shards",))
 
-carry = shard_map(body, mesh=mesh,
-                  in_specs=(P("shards", None), P("shards")),
-                  out_specs=P(), check_rep=False)(domain, mids)
+carry = jax.shard_map(body, mesh=mesh,
+                      in_specs=(P("shards", None), P("shards")),
+                      out_specs=P(), check_vma=False)(domain, mids)
 dropped = np.asarray(pol.finalize(carry, ctx))
 
 # ground truth: the identical schedule with shard DROP's rows deleted

@@ -530,7 +530,6 @@ def test_accumulate_microbatch_grads_front_door():
 @pytest.mark.parametrize("policy", R.COLLECTIVE_POLICIES)
 def test_collective_mean_policies_single_device(policy):
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
     x = jnp.asarray(np.random.RandomState(17).randn(8).astype(np.float32))
@@ -539,8 +538,8 @@ def test_collective_mean_policies_single_device(policy):
         m, r = R.collective_mean(v, ("data",), policy=policy, bits=8)
         return m, r
 
-    m, r = shard_map(f, mesh=mesh, in_specs=P(), out_specs=P(),
-                     check_rep=False)(x)
+    m, r = jax.shard_map(f, mesh=mesh, in_specs=P(), out_specs=P(),
+                         check_vma=False)(x)
     tol = 0.05 if policy == "compensated" else 1e-5   # 8-bit payload
     np.testing.assert_allclose(np.asarray(m), np.asarray(x),
                                atol=tol * max(1.0, float(jnp.abs(x).max())))

@@ -87,11 +87,44 @@ def test_mesh_kwarg_validation():
 
 def test_ambient_mesh_detection():
     assert R.ambient_mesh() is None
-    with R.default_mesh() as m:
+    with jax.set_mesh(R.default_mesh()):
         amb = R.ambient_mesh()
         assert amb is not None and tuple(amb.axis_names) == ("shards",)
-        del m
     assert R.ambient_mesh() is None
+
+
+AMBIENT_SNIPPET = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+import jax, jax.numpy as jnp, numpy as np
+from repro import reduce as R
+
+pol = R.get_policy("exact2")
+mesh = R.default_mesh()
+print("OUTSIDE", R.select_backend(pol).name)
+with jax.set_mesh(mesh):
+    print("AMBIENT", R.select_backend(pol).name, mesh.size)
+    print("TRACED", R.select_backend(pol, traced=True).name)
+    x = jnp.arange(4096.0).reshape(512, 8)
+    auto = np.asarray(R.reduce(x, policy="exact2"))
+base = np.asarray(R.reduce(x, policy="exact2", backend="blocked"))
+print("BITS", int(np.array_equal(auto, base)))
+"""
+
+
+def test_select_backend_picks_shard_map_under_ambient_mesh():
+    """``with jax.set_mesh(mesh):`` over 8 virtual CPU devices steers
+    auto-selection to shard_map for concrete arrays (never for traced
+    values), and the sharded result keeps exact2's bits."""
+    env = dict(os.environ, PYTHONPATH=str(REPO / "src"))
+    r = subprocess.run([sys.executable, "-c", AMBIENT_SNIPPET],
+                       capture_output=True, text=True, env=env, timeout=300)
+    assert r.returncode == 0, r.stderr[-3000:]
+    got = dict(ln.split(None, 1) for ln in r.stdout.strip().splitlines())
+    assert got["OUTSIDE"] == "blocked"
+    assert got["AMBIENT"] == "shard_map 8"
+    assert got["TRACED"] == "blocked"
+    assert got["BITS"] == "1"
 
 
 @pytest.mark.parametrize("policy", POLICIES)
@@ -126,7 +159,6 @@ def test_policy_merge_is_the_schedule_split(policy):
 
 def test_merge_across_accumulator_single_device():
     mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:1]), ("shards",))
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     acc = R.KahanAccumulator()
     x = jnp.asarray([1.5, 2.5])
@@ -135,8 +167,8 @@ def test_merge_across_accumulator_single_device():
         st = acc.push(acc.init(v), v)
         return acc.finalize(R.merge_across(acc, st, mesh.axis_names))
 
-    out = shard_map(f, mesh=mesh, in_specs=P(), out_specs=P(),
-                    check_rep=False)(x)
+    out = jax.shard_map(f, mesh=mesh, in_specs=P(), out_specs=P(),
+                        check_vma=False)(x)
     np.testing.assert_allclose(np.asarray(out), np.asarray(x))
 
 
@@ -224,7 +256,6 @@ for ndev in (1, 2, 8):
 # BinAccumulator declares merge_is_add: merge_across must take the psum
 # fast path and still match a single-device pass bit for bit
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 meshA = Mesh(np.asarray(jax.devices()), ("data",))
 xa = jnp.asarray((np.arange(8 * 4).reshape(8, 4) % 7 - 3) * 0.25,
                  dtype=jnp.float32)
@@ -232,8 +263,8 @@ acc = R.BinAccumulator(8.0)
 def accf(shard):
     st = acc.push(acc.init(shard[0]), shard[0])
     return acc.finalize(R.merge_across(acc, st, ("data",)))
-got = np.asarray(shard_map(accf, mesh=meshA, in_specs=P("data", None),
-                           out_specs=P(), check_rep=False)(xa))
+got = np.asarray(jax.shard_map(accf, mesh=meshA, in_specs=P("data", None),
+                               out_specs=P(), check_vma=False)(xa))
 direct = acc.init(xa[0])
 for row in xa:
     direct = acc.push(direct, row)
@@ -287,7 +318,8 @@ for pol in ("exact", "exact2", "procrastinate"):
     print(f"BSWEEP {pol} {int(ok)}")
 
 # auto-selection under an ambient multi-device mesh, bitwise vs blocked
-with mesh8:
+with jax.set_mesh(mesh8):
+    assert R.select_backend(R.get_policy("exact")).name == "shard_map"
     auto = np.asarray(R.reduce(vals, segment_ids=ids, num_segments=s,
                                policy="exact", block_size=bs))
 base = np.asarray(R.reduce(vals, segment_ids=ids, num_segments=s,
