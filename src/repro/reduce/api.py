@@ -30,6 +30,7 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from ..core import intac
 from .algebra import get_op
@@ -440,101 +441,113 @@ def reduce(values, *, segment_ids=None, num_segments: Optional[int] = None,
     >>> float(reduce(jnp.ones(4), op="poly", coeffs=(0.0, 1.0)))  # sum i
     6.0
     """
-    if on_overflow not in ("raise", "degrade"):
-        raise ValueError(f"on_overflow must be 'raise' or 'degrade', "
-                         f"got {on_overflow!r}")
-    if spec is None:
-        spec = ReduceSpec(op=op, policy=policy, backend=backend,
-                          block_size=block_size, contrib=contrib,
-                          interpret=interpret, coeffs=coeffs)
-    elif coeffs is not None and spec.coeffs is None:
-        spec = spec.replace(coeffs=coeffs)
-    # Resolve auto-selection and the mesh *before* the jit boundary: the
-    # dispatch cache keys on the concrete (spec, mesh, axis_names), so an
-    # activated-then-deactivated ambient mesh can never serve a stale
-    # cached executor choice.
-    pol = get_policy(spec.policy)
-    auto = spec.backend is None
-    traced = any(isinstance(x, jax.core.Tracer)
-                 for x in (values, segment_ids, weights))
-    bk = (select_backend(pol, mesh=mesh, traced=traced) if auto
-          else get_backend(spec.backend))
-    spec = spec if spec.backend == bk.name else spec.replace(backend=bk.name)
-    if bk.distributed:
-        if mesh is None:
-            mesh = ambient_mesh() or default_mesh()
-        if axis_names is not None:
-            axis_names = tuple(axis_names)
-    elif auto:
-        # auto-selection declined the mesh (single device, or unsupported
-        # policy): run the local backend.  A 1-device mesh dropping to the
-        # local path is the intended "scale if useful" contract, but
-        # explicit axis_names state distributed intent — refuse rather
-        # than silently reduce on one device.
-        if axis_names is not None:
-            raise ValueError(
-                "axis_names was given but backend auto-selection chose a "
-                "single-device executor (no multi-device mesh in reach); "
-                "pass backend='shard_map' and/or a multi-device mesh")
-        mesh = None
-    elif mesh is not None or axis_names is not None:
-        raise ValueError(f"backend {bk.name!r} is single-device; mesh/"
-                         f"axis_names only apply to distributed backends "
-                         f"(e.g. 'shard_map')")
-    values = jnp.asarray(values)
-    if values.ndim not in (1, 2):
-        raise ValueError(f"values must be (N,) or (N, D), "
-                         f"got shape {values.shape}")
-    squeeze_d = values.ndim == 1
-    if squeeze_d:
-        values = values[:, None]
+    with TraceAnnotation("repro.reduce") as span:
+        if on_overflow not in ("raise", "degrade"):
+            raise ValueError(f"on_overflow must be 'raise' or 'degrade', "
+                             f"got {on_overflow!r}")
+        if spec is None:
+            spec = ReduceSpec(op=op, policy=policy, backend=backend,
+                              block_size=block_size, contrib=contrib,
+                              interpret=interpret, coeffs=coeffs)
+        elif coeffs is not None and spec.coeffs is None:
+            spec = spec.replace(coeffs=coeffs)
+        # Resolve auto-selection and the mesh *before* the jit boundary: the
+        # dispatch cache keys on the concrete (spec, mesh, axis_names), so an
+        # activated-then-deactivated ambient mesh can never serve a stale
+        # cached executor choice.
+        pol = get_policy(spec.policy)
+        auto = spec.backend is None
+        traced = any(isinstance(x, jax.core.Tracer)
+                     for x in (values, segment_ids, weights))
+        bk = (select_backend(pol, mesh=mesh, traced=traced) if auto
+              else get_backend(spec.backend))
+        if spec.backend != bk.name:
+            spec = spec.replace(backend=bk.name)
+        if bk.distributed:
+            if mesh is None:
+                mesh = ambient_mesh() or default_mesh()
+            if axis_names is not None:
+                axis_names = tuple(axis_names)
+        elif auto:
+            # auto-selection declined the mesh (single device, or unsupported
+            # policy): run the local backend.  A 1-device mesh dropping to the
+            # local path is the intended "scale if useful" contract, but
+            # explicit axis_names state distributed intent — refuse rather
+            # than silently reduce on one device.
+            if axis_names is not None:
+                raise ValueError(
+                    "axis_names was given but backend auto-selection chose a "
+                    "single-device executor (no multi-device mesh in reach); "
+                    "pass backend='shard_map' and/or a multi-device mesh")
+            mesh = None
+        elif mesh is not None or axis_names is not None:
+            raise ValueError(f"backend {bk.name!r} is single-device; mesh/"
+                             f"axis_names only apply to distributed backends "
+                             f"(e.g. 'shard_map')")
+        values = jnp.asarray(values)
+        if values.ndim not in (1, 2):
+            raise ValueError(f"values must be (N,) or (N, D), "
+                             f"got shape {values.shape}")
+        squeeze_d = values.ndim == 1
+        if squeeze_d:
+            values = values[:, None]
+        width = values.shape[1]
 
-    # The algebra's one interception point: run the op's row-local
-    # ``pre`` here, above the jit boundary and above every executor, so
-    # the dispatch/degrade/shard machinery below only ever sees a plain
-    # (possibly wider) sum of the transformed rows.
-    op_ = get_op(spec.op)
-    if op_.requires_weights and weights is None:
-        raise ValueError(f"op {spec.op!r} requires per-row weights=")
-    if weights is not None and not op_.takes_weights:
-        raise ValueError(f"op {spec.op!r} takes no weights")
-    if op_.requires_coeffs and spec.coeffs is None:
-        raise ValueError(f"op {spec.op!r} requires coeffs=")
-    if weights is not None:
-        weights = jnp.asarray(weights)
-        if weights.ndim == 2 and weights.shape[-1] == 1:
-            weights = weights[:, 0]
-        if weights.ndim != 1 or weights.shape[0] != values.shape[0]:
-            raise ValueError(
-                f"weights must be (N,) or (N, 1) matching values' "
-                f"N={values.shape[0]}, got shape {weights.shape}")
-    values = op_.pre(values, weights=weights, coeffs=spec.coeffs)
+        # The algebra's one interception point: run the op's row-local
+        # ``pre`` here, above the jit boundary and above every executor, so
+        # the dispatch/degrade/shard machinery below only ever sees a plain
+        # (possibly wider) sum of the transformed rows.
+        op_ = get_op(spec.op)
+        if op_.requires_weights and weights is None:
+            raise ValueError(f"op {spec.op!r} requires per-row weights=")
+        if weights is not None and not op_.takes_weights:
+            raise ValueError(f"op {spec.op!r} takes no weights")
+        if op_.requires_coeffs and spec.coeffs is None:
+            raise ValueError(f"op {spec.op!r} requires coeffs=")
+        if weights is not None:
+            weights = jnp.asarray(weights)
+            if weights.ndim == 2 and weights.shape[-1] == 1:
+                weights = weights[:, 0]
+            if weights.ndim != 1 or weights.shape[0] != values.shape[0]:
+                raise ValueError(
+                    f"weights must be (N,) or (N, 1) matching values' "
+                    f"N={values.shape[0]}, got shape {weights.shape}")
+        with TraceAnnotation("repro.reduce.pre"):
+            values = op_.pre(values, weights=weights, coeffs=spec.coeffs)
 
-    segmented = segment_ids is not None
-    if segmented:
-        if num_segments is None:
-            raise ValueError("num_segments (static int) is required with "
-                             "segment_ids")
-        segment_ids = jnp.asarray(segment_ids)
-    else:
-        if num_segments is not None:
-            raise ValueError("num_segments was given without segment_ids; "
-                             "pass both for a segmented reduction")
-        num_segments = 1
-        segment_ids = jnp.zeros((values.shape[0],), jnp.int32)
+        segmented = segment_ids is not None
+        if segmented:
+            if num_segments is None:
+                raise ValueError("num_segments (static int) is required with "
+                                 "segment_ids")
+            segment_ids = jnp.asarray(segment_ids)
+        else:
+            if num_segments is not None:
+                raise ValueError("num_segments was given without segment_ids; "
+                                 "pass both for a segmented reduction")
+            num_segments = 1
+            segment_ids = jnp.zeros((values.shape[0],), jnp.int32)
 
-    if on_overflow == "degrade":
-        if isinstance(values, jax.core.Tracer):
+        if on_overflow == "degrade" and isinstance(values, jax.core.Tracer):
             raise ValueError(
                 "on_overflow='degrade' re-plans the reduction from runtime "
                 "flags and is eager-only; call reduce outside jit, or keep "
                 "on_overflow='raise'")
-        out, status = _reduce_degrade(
-            values, segment_ids, spec=spec, num_segments=int(num_segments),
-            segmented=segmented, squeeze_d=squeeze_d, mesh=mesh,
-            axis_names=axis_names)
-        return (out, status) if with_status else out
-    return _dispatch(values, segment_ids, spec=spec,
-                     num_segments=int(num_segments), segmented=segmented,
-                     squeeze_d=squeeze_d, mesh=mesh, axis_names=axis_names,
-                     with_status=with_status)
+        with TraceAnnotation("repro.reduce.dispatch"):
+            if on_overflow == "degrade":
+                out = _reduce_degrade(
+                    values, segment_ids, spec=spec,
+                    num_segments=int(num_segments), segmented=segmented,
+                    squeeze_d=squeeze_d, mesh=mesh, axis_names=axis_names)
+                out = out if with_status else out[0]
+            else:
+                out = _dispatch(values, segment_ids, spec=spec,
+                                num_segments=int(num_segments),
+                                segmented=segmented, squeeze_d=squeeze_d,
+                                mesh=mesh, axis_names=axis_names,
+                                with_status=with_status)
+        if span.is_enabled():
+            span.set_metadata(rows=values.shape[0], width=width,
+                              segments=int(num_segments), policy=spec.policy,
+                              op=spec.op)
+        return out

@@ -48,6 +48,7 @@ from typing import Callable, List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro import reduce as _reduce
 from repro.models import decode_step, forward, init_caches, pad_caches_to
@@ -222,18 +223,34 @@ class Engine:
     def run(self, *, on_step: Optional[Callable] = None) -> List[Result]:
         """Drain every submitted request; returns results in submission
         order.  ``on_step(engine, step)`` fires after each engine step
-        (fault injection, probes)."""
+        (fault injection, probes).
+
+        Each step is a ``repro.engine.step`` profiler span (stats
+        ``prefill_chunks``, ``decode_slots``) holding its phases'
+        spans, ``repro.engine.admit``, ``.prefill`` and ``.decode``;
+        ``repro.engine.sync`` marks each wait for sampled tokens, and
+        ``repro.engine.first_token`` each request's first token (stats
+        ``queue_ms``, ``prefill_ms``, ``chunks``).  See docs/serving.md
+        "Measuring it"."""
         sched = self.scheduler
         self._clock = 0
         self._rid_base = sched._next_deliver
         self._lp_vals, self._lp_ids = [], []
         delivered: List[Result] = []
         while sched.has_work():
-            sched.advance(self._clock)
-            progressed = bool(sched.admit())
-            progressed |= self._prefill_work()
-            progressed |= self._decode_work()
-            delivered.extend(sched.pop_ready())
+            with TraceAnnotation("repro.engine.step") as step:
+                with TraceAnnotation("repro.engine.admit"):
+                    sched.advance(self._clock)
+                    admitted = sched.admit()
+                with TraceAnnotation("repro.engine.prefill"):
+                    chunks = self._prefill_work()
+                with TraceAnnotation("repro.engine.decode"):
+                    slots = self._decode_work()
+                delivered.extend(sched.pop_ready())
+                if step.is_enabled():
+                    step.set_metadata(prefill_chunks=chunks,
+                                      decode_slots=slots)
+            progressed = bool(admitted or chunks or slots)
             if on_step is not None:
                 on_step(self, self._clock)
                 delivered.extend(sched.pop_ready())
@@ -284,12 +301,12 @@ class Engine:
 
     # -- phases ------------------------------------------------------------
 
-    def _prefill_work(self) -> bool:
+    def _prefill_work(self) -> int:
         """One prompt chunk per mid-prefill slot (chunked prefill: long
-        prompts interleave with decode steps instead of stalling them)."""
-        worked = False
-        for tr in self.scheduler.in_state("prefill"):
-            worked = True
+        prompts interleave with decode steps instead of stalling them);
+        returns how many chunks (or whole prompts) were dispatched."""
+        work = self.scheduler.in_state("prefill")
+        for tr in work:
             prompt = list(tr.request.prompt)
             if self._extend_ok:
                 chunk = self.prefill_chunk
@@ -311,8 +328,17 @@ class Engine:
                     self.params, self._caches, jnp.int32(tr.slot),
                     jnp.asarray(toks))
                 tr.prefill_pos = len(prompt)
-            self._first_token(tr, logits)
-        return worked
+            with TraceAnnotation("repro.engine.first_token") as span:
+                self._first_token(tr, logits)
+                if span.is_enabled():
+                    chunks = -(-len(prompt) // self.prefill_chunk) \
+                        if self._extend_ok else 1
+                    span.set_metadata(
+                        queue_ms=1e3 * (tr.admit_wall - tr.submit_wall),
+                        prefill_ms=1e3 * (time.perf_counter()
+                                          - tr.admit_wall),
+                        chunks=chunks)
+        return len(work)
 
     def _first_token(self, tr: TrackedRequest, logits) -> None:
         """Prefill just completed: sample the request's first token from
@@ -324,8 +350,10 @@ class Engine:
             jnp.asarray([custom], jnp.int32), jnp.asarray([idv], jnp.int32),
             jnp.asarray([0], jnp.int32),
             jnp.asarray([max(req.temperature, 0.0)], jnp.float32))
-        t = int(np.asarray(tok)[0])
-        self._lp_vals.append(np.asarray(lp, np.float32))
+        with TraceAnnotation("repro.engine.sync"):
+            t = int(np.asarray(tok)[0])
+            lp_np = np.asarray(lp, np.float32)
+        self._lp_vals.append(lp_np)
         self._lp_ids.append(np.asarray([tr.rid - self._rid_base], np.int32))
         tr.out = list(req.prompt) + [t]
         tr.last_token = t
@@ -333,13 +361,14 @@ class Engine:
         tr.state = "decode"
         self._maybe_retire(tr, t)
 
-    def _decode_work(self) -> bool:
+    def _decode_work(self) -> int:
         """One lock-step decode step across every decode-state slot; idle
         and mid-prefill slots ride along masked (fixed shapes => one
-        compiled program, and per-row bitwise independence)."""
+        compiled program, and per-row bitwise independence).  Returns
+        how many slots decoded."""
         dec = self.scheduler.in_state("decode")
         if not dec:
-            return False
+            return 0
         b = self.max_batch
         toks = np.zeros((b, 1), np.int32)
         pos = np.zeros(b, np.int32)
@@ -363,11 +392,13 @@ class Engine:
         tok, lp = self._sample(self._base_key, logits,
                                jnp.asarray(custom), jnp.asarray(idv),
                                jnp.asarray(steps), jnp.asarray(temps))
-        tok_np = np.asarray(tok)
+        with TraceAnnotation("repro.engine.sync"):
+            tok_np = np.asarray(tok)
+            lp_np = np.asarray(lp, np.float32)
         ids = np.full(b, _reduce.OUT_OF_RANGE_LABEL, np.int32)
         for tr in dec:
             ids[tr.slot] = tr.rid - self._rid_base
-        self._lp_vals.append(np.asarray(lp, np.float32))
+        self._lp_vals.append(lp_np)
         self._lp_ids.append(ids)
         for tr in dec:
             t = int(tok_np[tr.slot])
@@ -375,7 +406,7 @@ class Engine:
             tr.last_token = t
             tr.new_tokens += 1
             self._maybe_retire(tr, t)
-        return True
+        return len(dec)
 
     def _maybe_retire(self, tr: TrackedRequest, last_tok: int) -> None:
         req = tr.request

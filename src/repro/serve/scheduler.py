@@ -52,7 +52,7 @@ class TrackedRequest:
     out: List[int] = dataclasses.field(default_factory=list)
     submit_wall: float = 0.0
     arrive_wall: float = 0.0
-    finish_wall: float = 0.0
+    admit_wall: float = 0.0
     finish_reason: Optional[str] = None
 
     @property
@@ -132,6 +132,7 @@ class Scheduler:
             tr.slot = free[0]
             tr.state = "prefill"
             tr.prefill_pos = 0
+            tr.admit_wall = time.perf_counter()
             self.slots[free[0]] = tr.rid
             admitted.append(tr)
         return admitted
@@ -154,7 +155,6 @@ class Scheduler:
             self._queue.remove(tr.rid)
         tr.state = "done"
         tr.finish_reason = tr.finish_reason or reason
-        tr.finish_wall = time.perf_counter()
         self._results[tr.rid] = result
 
     def pop_ready(self) -> List[Any]:
