@@ -443,6 +443,8 @@ class FloatCountArithmetic(LintRule):
 _POLICY_HOOKS = {
     # hook -> (min positional args after self, required kwargs)
     "prepare_ctx": (2, ()),
+    "domain_args": (1, ()),
+    "map_rows": (1, ()),
     "to_domain": (2, ()),
     "prepare": (1, ()),
     "contrib": (2, ()),
@@ -552,7 +554,7 @@ def check_registries() -> List[Finding]:
                        f"{b.name!r}")
         kwargs = list(_BACKEND_RUN_KWARGS)
         if getattr(b, "staged", False):
-            kwargs.append("program")
+            kwargs += ["program", "to_domain", "prep_state"]
         if getattr(b, "distributed", False):
             kwargs += ["mesh", "axis_names"]
         deficit = _sig_accepts(b.run, min_pos=3, kwargs=kwargs)

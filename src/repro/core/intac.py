@@ -76,6 +76,23 @@ def _ldexp2(x: jnp.ndarray, e) -> jnp.ndarray:
     return jnp.ldexp(jnp.ldexp(x, h), e - h)
 
 
+def ldexp2_factors(e) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The two half-exponent factors of ``_ldexp2(x, e)``: (2^h, 2^(e-h))
+    with h = e // 2, so ``x * f1 * f2`` is ``_ldexp2(x, e)`` bit for bit
+    at the exponents the integer tiers use (|e| <= 129; each step is an
+    exact power-of-two multiply, flushed below the normal range as the
+    hardware flushes it).  Computed once, outside the elementwise map, so
+    a kernel body needs only the two multiplies."""
+    e = jnp.asarray(e, jnp.int32)
+    h = e // 2
+
+    def pow2(k):            # from the exponent bits: exact on any backend
+        k = jnp.clip(k, -126, 127)
+        return jax.lax.bitcast_convert_type((k + 127) << 23, jnp.float32)
+
+    return pow2(h), pow2(e - h)
+
+
 def choose_scale(max_abs: jnp.ndarray, num_terms: int,
                  qbits: int = 30) -> jnp.ndarray:
     """Power-of-two scale s.t. n * |x|_max * scale < 2^qbits.
@@ -463,6 +480,14 @@ def bin_split(x: jnp.ndarray, e_ref, *, bits: int = BIN_BITS,
     num=RES_NUM_BINS`` anchored at its quantum.
     """
     v = _ldexp2(x.astype(jnp.float32), -jnp.asarray(e_ref, jnp.int32))
+    return jnp.stack(bin_digits(v, bits=bits, num=num))
+
+
+def bin_digits(v: jnp.ndarray, *, bits: int, num: int) -> list:
+    """The digit extraction of ``bin_split`` on values already scaled to
+    the window (``v = x * 2^-e_ref``): ``num`` int32 digit arrays, most
+    significant first.  Multiplies by 2^bits, round-half-even, subtracts
+    and converts only — what a kernel body lowers."""
     radix = jnp.float32(1 << bits)
     digits = []
     for _ in range(num):
@@ -470,7 +495,7 @@ def bin_split(x: jnp.ndarray, e_ref, *, bits: int = BIN_BITS,
         d = jnp.round(s)
         v = s - d                         # exact: both multiples of ulp(s)
         digits.append(d.astype(jnp.int32))
-    return jnp.stack(digits)
+    return digits
 
 
 def _bin_carry_resolve(bins: jnp.ndarray, bits: int) -> list:

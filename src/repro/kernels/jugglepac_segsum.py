@@ -34,7 +34,18 @@ rather than by duplicated code.  Multi-block supertiles change only *when*
 tiles move, never the fold order: block j still folds before block j+1,
 so results are bitwise identical at any ``blocks_per_step``.
 
-VMEM budget per step: K*B*D (values) + K*B (ids) + carry_len*S*D floats —
+The input stage of the integer tiers runs here too, as the paper's
+pipelined input stage: given ``to_domain`` (``Policy.map_rows``), the
+kernel reads raw (K*B, D) f32 rows and, per block, zeroes the dropped
+rows, maps the block into the policy's domain in VMEM (exact2: the
+quantized part and seven residual digit planes, 8·D wide) and folds it —
+so the wide domain never exists in HBM.  The map's scalars (the scale,
+its exact power-of-two inverse factors) arrive in SMEM, computed once
+outside the kernel; the body needs only multiplies, round-half-even,
+converts, shifts and masks.  ``ref`` and ``blocked`` call the same map
+per block, so the bitwise contract still holds by construction.
+
+VMEM budget per step: K*B*D (values) + K*B (ids) + carry_len*S*W floats —
 the callers (ops.segment_sum, the reduce pallas backend) tile the label
 space when the carry would exceed the budget, and ``blocks_per_step_for``
 sizes K so the double-buffered input window stays modest (the software
@@ -55,8 +66,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.reduce.backends import OUT_OF_RANGE_LABEL
+from repro.reduce.backends import OUT_OF_RANGE_LABEL, carry_width, map_block
 from repro.reduce.program import block_contrib
 
 #: bytes of f32 input tiles one grid step may hold; with Pallas's grid
@@ -76,11 +88,15 @@ def blocks_per_step_for(block_rows: int, width: int) -> int:
     return int(max(1, min(8, _INPUT_WINDOW_BYTES // max(per_block, 1))))
 
 
-def _segsum_policy_kernel(ids_ref, vals_ref, *out_refs, num_segments: int,
+def _segsum_policy_kernel(ids_ref, vals_ref, *refs, num_segments: int,
                           seg_offset: int, policy, program,
                           block_rows: int, blocks_per_step: int,
-                          interpret: bool):
+                          interpret: bool, to_domain, num_prep: int):
     """The streaming schedule with the accuracy-policy carry baked in.
+
+    With ``to_domain``, ``vals_ref`` holds raw rows and ``refs`` starts
+    with the (num_prep,) SMEM vector of the map's scalars; each block is
+    masked and mapped (``backends.map_block``) before its contrib.
 
     The staged contrib (``block_contrib`` — the dot form; the lane form
     only under interpret mode) and ``policy.update`` are traced straight
@@ -95,6 +111,11 @@ def _segsum_policy_kernel(ids_ref, vals_ref, *out_refs, num_segments: int,
     fold order is untouched — bitwise identical at any supertile depth.
     """
     step = pl.program_id(0)
+    prep = ()
+    if num_prep:
+        prep = tuple(refs[0][k] for k in range(num_prep))
+        refs = refs[1:]
+    out_refs = refs
 
     @pl.when(step == 0)
     def _init():
@@ -111,6 +132,7 @@ def _segsum_policy_kernel(ids_ref, vals_ref, *out_refs, num_segments: int,
         ids, vals = nxt                             # (B, 1), (B, W)
         if j + 1 < blocks_per_step:
             nxt = load(j + 1)       # prefetch while this block folds
+        vals = map_block(vals, ids, to_domain, prep)
         contrib = block_contrib(vals, ids.reshape(block_rows),
                                 num_segments, policy, program,
                                 seg_offset=seg_offset)
@@ -131,7 +153,8 @@ def segsum_policy_pallas(values: jnp.ndarray, segment_ids: jnp.ndarray,
                          num_segments: int, *, policy,
                          block_rows: int = 512, seg_offset: int = 0,
                          interpret: bool = False, program=None,
-                         blocks_per_step=None):
+                         blocks_per_step=None, to_domain=None,
+                         prep_state=()):
     """values (N, W) already in ``policy``'s domain (``Policy.prepare``
     already ran; W may exceed the raw feature width D — e.g. exact2's
     quantized|residual halves), ids (N,) int32 -> tuple of
@@ -146,6 +169,14 @@ def segsum_policy_pallas(values: jnp.ndarray, segment_ids: jnp.ndarray,
     integer ``+0`` is trivial), so the supertile depth never changes the
     result bits.
 
+    With ``to_domain`` (``Policy.map_rows``) and ``prep_state`` (its f32
+    scalars, ``Policy.domain_args``), ``values`` are the raw (N, D) rows
+    instead, at any N: only the labels pad to whole supertiles, and the
+    kernel zeroes every row whose label is the sentinel — dropped rows
+    and the unspecified rows past N of the last supertile — before it
+    maps each block into the domain.  The supertile depth is then sized
+    from the raw width D.
+
     ``program`` is a planned ``BlockProgram`` (contrib mode);
     ``blocks_per_step=None`` sizes the supertile from the VMEM window
     (``blocks_per_step_for``).  The compiled kernel runs only the dot
@@ -158,39 +189,45 @@ def segsum_policy_pallas(values: jnp.ndarray, segment_ids: jnp.ndarray,
             "one-hot dot contrib (Mosaic has no scatter-add); plan "
             "contrib='dot', or use backend='blocked' for the lane form")
     n, d = values.shape
-    if n % block_rows:
+    if to_domain is None and n % block_rows:
         raise ValueError(f"segsum_policy_pallas: N={n} must be a multiple "
                          f"of block_rows={block_rows}; pad in the caller")
-    nb = n // block_rows
+    nb = -(-n // block_rows)
     if blocks_per_step is None:
         blocks_per_step = blocks_per_step_for(block_rows, d)
     bps = max(1, min(int(blocks_per_step), nb))
-    extra = (-nb) % bps
-    if extra:                       # whole sentinel blocks: fold identity
-        values = jnp.pad(values, ((0, extra * block_rows), (0, 0)))
-        segment_ids = jnp.pad(segment_ids, (0, extra * block_rows),
+    pad = (-nb) % bps * block_rows + (nb * block_rows - n)
+    if pad:                         # whole sentinel blocks: fold identity
+        if to_domain is None:
+            values = jnp.pad(values, ((0, pad), (0, 0)))
+        segment_ids = jnp.pad(segment_ids, (0, pad),
                               constant_values=OUT_OF_RANGE_LABEL)
-        nb += extra
     ids2 = segment_ids.reshape(-1, 1).astype(jnp.int32)
+    num_prep = len(prep_state) if to_domain is not None else 0
     kernel = functools.partial(_segsum_policy_kernel,
                                num_segments=num_segments,
                                seg_offset=seg_offset, policy=policy,
                                program=program, block_rows=block_rows,
-                               blocks_per_step=bps, interpret=interpret)
+                               blocks_per_step=bps, interpret=interpret,
+                               to_domain=to_domain, num_prep=num_prep)
     # the policy's init is the one source of truth for per-component carry
     # shapes/dtypes (exact2 mixes int32 limbs with f32 residuals, and its
-    # carries are half the domain width); the zeros are traced away
-    carry0 = policy.init(num_segments, d)
+    # carries are narrower than the domain); the zeros are traced away
+    carry0 = policy.init(num_segments, carry_width(policy, d, to_domain))
+    in_specs = [pl.BlockSpec((bps * block_rows, 1), lambda b: (b, 0)),
+                pl.BlockSpec((bps * block_rows, d), lambda b: (b, 0))]
+    args = [ids2, values]
+    if num_prep:
+        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
+        args.append(jnp.stack([jnp.asarray(a, jnp.float32)
+                               for a in prep_state]))
     out = pl.pallas_call(
         kernel,
-        grid=(nb // bps,),
-        in_specs=[
-            pl.BlockSpec((bps * block_rows, 1), lambda b: (b, 0)),
-            pl.BlockSpec((bps * block_rows, d), lambda b: (b, 0)),
-        ],
+        grid=(ids2.shape[0] // (bps * block_rows),),
+        in_specs=in_specs,
         out_specs=[pl.BlockSpec(c.shape, lambda b: (0, 0))
                    for c in carry0],
         out_shape=[jax.ShapeDtypeStruct(c.shape, c.dtype) for c in carry0],
         interpret=interpret,
-    )(ids2, values)
+    )(*args)
     return tuple(out) if isinstance(out, (list, tuple)) else (out,)

@@ -129,6 +129,17 @@ def _status_false() -> ReduceStatus:
                         jnp.asarray(False), jnp.asarray(0, jnp.int32))
 
 
+def _maps_per_block(backend, policy) -> bool:
+    """Where the domain map runs: True when the block schedule maps each
+    block of raw rows into the domain as it folds it (a staged executor,
+    and a tier whose map is sized by the stream's max-|value| statistic:
+    ``exact``, ``exact2``, ``procrastinate``), so no N x domain-width
+    array is built; False when ``_dispatch`` maps the whole stream
+    first (``fast``/``compensated``, whose map is a cast, and executors
+    that take prepared values)."""
+    return backend.staged and policy.needs_max_stat
+
+
 @functools.partial(jax.jit, static_argnames=("spec", "num_segments",
                                              "segmented", "squeeze_d",
                                              "mesh", "axis_names",
@@ -171,18 +182,14 @@ def _dispatch(values, segment_ids, *, spec: ReduceSpec, num_segments: int,
         out = jnp.zeros((num_segments, d), jnp.float32)
     else:
         segment_ids = mask_out_of_range(segment_ids, num_segments)
-        # zero dropped rows' payloads too: the one-hot schedule ignores
-        # them regardless, but policy.prepare must not see them (e.g. the
-        # exact policy sizes its quantization scale from max |value| — a
-        # huge sentinel-labeled row would poison the scale for kept rows)
-        values = jnp.where((segment_ids >= 0)[:, None], values,
-                           jnp.zeros((), values.dtype))
+        kept = (segment_ids >= 0)[:, None]
         if with_status:
-            # post-mask, so a NaN/Inf in a *dropped* row never trips the
-            # flag (it provably never enters any tier either)
+            # kept rows only, so a NaN/Inf in a *dropped* row never trips
+            # the flag (it provably never enters any tier either); two
+            # reductions that write no array
             status = status._replace(
-                nonfinite=jnp.logical_not(jnp.all(jnp.isfinite(values))),
-                kept_rows=jnp.sum((segment_ids >= 0).astype(jnp.int32)))
+                nonfinite=jnp.any(kept & ~jnp.isfinite(values)),
+                kept_rows=jnp.sum(kept.astype(jnp.int32)))
         run_kw = ({"mesh": mesh, "axis_names": axis_names}
                   if backend.distributed else {})
         if backend.staged:
@@ -199,28 +206,30 @@ def _dispatch(values, segment_ids, *, spec: ReduceSpec, num_segments: int,
                 domain_width=policy.domain_width(d),
                 block_size=spec.block_size, contrib=spec.contrib,
                 op=spec.op, plans_lanes=executor.plans_lanes)
-        if backend.staged and backend.distributed:
-            # the staged distributed path: compute only the global
-            # statistic here (one max-reduce), hand the *raw* rows to the
-            # backend, and let every shard run the elementwise
-            # ``to_domain`` on its own slice against the shared ctx —
-            # bit-identical to whole-stream prepare (to_domain is
-            # row-local), but the expensive digitization parallelizes and
-            # the narrow raw rows are what crosses the sharding boundary.
-            v32 = values.astype(jnp.float32)
-            m = (jnp.max(jnp.abs(v32)) if policy.needs_max_stat else None)
+        if _maps_per_block(backend, policy):
+            # compute only the global statistic here — one reduction of
+            # the kept rows' |value| that writes no array — and hand the
+            # *raw* rows to the block schedule, which zeroes each block's
+            # dropped rows and maps it into the domain as it folds it
+            # (in VMEM, on pallas; per shard, under shard_map).  Bit-
+            # identical to whole-stream prepare (the map is row-local),
+            # without the N x domain-width array in HBM.  Dropped rows
+            # stay out of the statistic: a huge sentinel-labeled row
+            # must not poison the scale for the kept rows.
+            m = jnp.max(jnp.where(kept, jnp.abs(values.astype(jnp.float32)),
+                                  jnp.float32(0)))
             ctx = policy.prepare_ctx(m, n)
-            prep = () if ctx is None else (ctx,)
-
-            def _to_domain(v, *p):
-                return policy.to_domain(v, p[0] if p else None)
-
-            carry = backend.run(v32, segment_ids, num_segments,
+            carry = backend.run(values, segment_ids, num_segments,
                                 policy=policy, block_size=spec.block_size,
                                 interpret=spec.interpret,
-                                to_domain=_to_domain, prep_state=prep,
+                                to_domain=policy.map_rows,
+                                prep_state=policy.domain_args(ctx),
                                 **run_kw)
         else:
+            # zero dropped rows' payloads: the one-hot schedule ignores
+            # them, but a NaN there would still poison the dot, and an
+            # integer tier's prepare sizes its scale from max |value|
+            values = jnp.where(kept, values, jnp.zeros((), values.dtype))
             domain, ctx = policy.prepare(values, n)
             carry = backend.run(domain, segment_ids, num_segments,
                                 policy=policy, block_size=spec.block_size,
@@ -533,7 +542,12 @@ def reduce(values, *, segment_ids=None, num_segments: Optional[int] = None,
                 "on_overflow='degrade' re-plans the reduction from runtime "
                 "flags and is eager-only; call reduce outside jit, or keep "
                 "on_overflow='raise'")
-        with TraceAnnotation("repro.reduce.dispatch"):
+        with TraceAnnotation("repro.reduce.dispatch") as dspan:
+            if dspan.is_enabled():
+                # where the domain map runs: per schedule block inside
+                # the executor, or over the whole stream before it
+                dspan.set_metadata(
+                    domain="block" if _maps_per_block(bk, pol) else "stream")
             if on_overflow == "degrade":
                 out = _reduce_degrade(
                     values, segment_ids, spec=spec,

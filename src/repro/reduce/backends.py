@@ -63,9 +63,13 @@ class Backend:
     """A registered executor of the block schedule.
 
     ``run(values, ids, num_segments, policy=..., block_size=...,
-    interpret=...)`` receives domain-prepared (N, D) values (f32 or int32 —
-    ``Policy.prepare`` already ran) and returns the policy carry tuple of
-    (num_segments, D) arrays, *not yet finalized*.
+    interpret=...)`` returns the policy carry tuple of (num_segments, D)
+    arrays, *not yet finalized*.  By default it receives domain-prepared
+    (N, D) values (f32 or int32 — ``Policy.prepare`` already ran).  A
+    staged executor also takes ``to_domain=``/``prep_state=``: then the
+    values arrive raw, and each schedule block is masked (dropped rows
+    zeroed), mapped with ``to_domain(block, *prep_state)`` and folded in
+    one step, so the domain is never built for the whole stream.
     """
 
     name: str
@@ -76,10 +80,10 @@ class Backend:
     #: (threaded by ``reduce`` from its own kwargs or the ambient mesh)
     distributed: bool = False
     #: staged executors additionally accept ``program=`` (a planned
-    #: ``BlockProgram``: contrib mode + stage cost hints); distributed
-    #: staged executors also take ``to_domain=``/``prep_state=`` so the
-    #: domain map runs per shard.  Off by default so pre-staged custom
-    #: backends keep their old ``run`` signature.
+    #: ``BlockProgram``: contrib mode + stage cost hints) and
+    #: ``to_domain=``/``prep_state=``, so the domain map runs per block
+    #: (and, under shard_map, per shard).  Off by default so pre-staged
+    #: custom backends keep their old ``run`` signature.
     staged: bool = False
     #: whether ``contrib="auto"`` may plan the lane-parallel scatter form
     #: for this executor (the compiled pallas kernel runs only the dot)
@@ -229,6 +233,24 @@ def _pad_to_blocks(values, segment_ids, block_size):
             segment_ids.reshape(nb, block_size).astype(jnp.int32), nb)
 
 
+def map_block(vals, ids, to_domain, prep_state):
+    """The per-block domain map of the staged executors: zero the
+    block's dropped rows (a sentinel row's payload — NaN, Inf, 1e38 —
+    must never reach ``to_domain``), then map the block.  The identity
+    when ``to_domain`` is None (values already in the domain)."""
+    if to_domain is None:
+        return vals
+    kept = (ids >= 0).reshape(-1, 1)
+    return to_domain(jnp.where(kept, vals, jnp.zeros((), vals.dtype)),
+                     *prep_state)
+
+
+def carry_width(policy: Policy, d: int, to_domain) -> int:
+    """Domain width the carry is built for: raw rows map to
+    ``policy.domain_width(d)`` columns."""
+    return d if to_domain is None else policy.domain_width(d)
+
+
 def _block_contrib(vals, ids, num_segments, policy, program=None):
     """One gather stage for one (B, W) block — the staged program's
     contrib step, shared verbatim with the pallas kernel body
@@ -248,11 +270,14 @@ def _block_contrib(vals, ids, num_segments, policy, program=None):
                               "readable schedule oracle")
 def _run_ref(values, segment_ids, num_segments, *, policy: Policy,
              block_size: int = 512, interpret: Optional[bool] = None,
-             program: Optional[BlockProgram] = None):
+             program: Optional[BlockProgram] = None,
+             to_domain=None, prep_state=()):
     vb, ib, nb = _pad_to_blocks(values, segment_ids, block_size)
-    carry = policy.init(num_segments, values.shape[1])
+    carry = policy.init(num_segments,
+                        carry_width(policy, values.shape[1], to_domain))
     for b in range(nb):
-        contrib = _block_contrib(vb[b], ib[b], num_segments, policy,
+        vals = map_block(vb[b], ib[b], to_domain, prep_state)
+        contrib = _block_contrib(vals, ib[b], num_segments, policy,
                                  program)
         carry = policy.update(carry, contrib)
         # pin the block boundary: without it XLA may fuse the unrolled
@@ -267,15 +292,18 @@ def _run_ref(values, segment_ids, num_segments, *, policy: Policy,
                               "CPU/GPU default")
 def _run_blocked(values, segment_ids, num_segments, *, policy: Policy,
                  block_size: int = 512, interpret: Optional[bool] = None,
-                 program: Optional[BlockProgram] = None):
+                 program: Optional[BlockProgram] = None,
+                 to_domain=None, prep_state=()):
     vb, ib, nb = _pad_to_blocks(values, segment_ids, block_size)
 
     def step(carry, blk):
         vals, ids = blk
+        vals = map_block(vals, ids, to_domain, prep_state)
         contrib = _block_contrib(vals, ids, num_segments, policy, program)
         return policy.update(carry, contrib), None
 
-    carry0 = policy.init(num_segments, values.shape[1])
+    carry0 = policy.init(num_segments,
+                         carry_width(policy, values.shape[1], to_domain))
     carry, _ = jax.lax.scan(step, carry0, (vb, ib))
     return carry
 
@@ -289,25 +317,31 @@ def _run_blocked(values, segment_ids, num_segments, *, policy: Policy,
 def _run_pallas(values, segment_ids, num_segments, *, policy: Policy,
                 block_size: int = 512, interpret: Optional[bool] = None,
                 program: Optional[BlockProgram] = None,
-                blocks_per_step: Optional[int] = None):
+                blocks_per_step: Optional[int] = None,
+                to_domain=None, prep_state=()):
     from repro.kernels import jugglepac_segsum as _ss
     from repro.kernels.ops import seg_tile_for
     if interpret is None:
         interpret = interpret_default()
     d = values.shape[1]
-    # same padding contract as every backend, flattened back for the grid
-    vb, ib, _ = _pad_to_blocks(values, segment_ids, block_size)
-    values = vb.reshape(-1, d)
-    segment_ids = ib.reshape(-1)
+    if to_domain is None:
+        # same padding contract as every backend, flattened back for the
+        # grid (raw rows are not copied: the kernel masks their tail)
+        vb, ib, _ = _pad_to_blocks(values, segment_ids, block_size)
+        values = vb.reshape(-1, d)
+        segment_ids = ib.reshape(-1)
     # VMEM-budget label tiling, shared with kernels.ops.segment_sum
-    seg_tile = seg_tile_for(num_segments, d, policy.carry_len)
+    seg_tile = seg_tile_for(num_segments,
+                            carry_width(policy, d, to_domain),
+                            policy.carry_len)
     parts = []
     for off in range(0, num_segments, seg_tile):
         s = min(seg_tile, num_segments - off)
         parts.append(_ss.segsum_policy_pallas(
             values, segment_ids, s, policy=policy,
             block_rows=block_size, seg_offset=off, interpret=interpret,
-            program=program, blocks_per_step=blocks_per_step))
+            program=program, blocks_per_step=blocks_per_step,
+            to_domain=to_domain, prep_state=prep_state))
     if len(parts) == 1:
         return parts[0]
     return tuple(jnp.concatenate([p[i] for p in parts], axis=0)
@@ -338,18 +372,17 @@ def _run_shard_map(values, segment_ids, num_segments, *, policy: Policy,
     exactly as on every other backend.
 
     ``to_domain`` moves the domain map *inside* the shards: when given
-    (the staged path ``reduce`` drives), ``values`` arrive raw and each
-    shard maps its own row slice into the policy domain —
-    ``to_domain(local_rows, *prep_state)`` with ``prep_state`` the
-    globally-computed, replicated finalize context (quantization scale /
-    window anchor).  ``Policy.to_domain`` is row-local by contract, so
-    the per-shard map is bit-identical to slicing a whole-stream domain
-    — zero bits change — while the expensive digitization (exact2's
-    residual bin_split is the dominant smoke-size cost) now scales with
-    the shard count instead of serializing on one device, and only the
-    narrow raw rows cross the host-to-device boundary, not the widened
-    domain planes.  ``to_domain=None`` keeps the legacy contract:
-    ``values`` already domain-prepared (direct ``backend.run`` callers).
+    (the path ``reduce`` drives for the integer tiers), ``values`` arrive
+    raw and each shard's local executor maps its own blocks into the
+    policy domain — ``to_domain(block, *prep_state)`` with
+    ``prep_state`` the globally-computed, replicated scalars of the
+    finalize context (quantization scale / window anchor).
+    ``Policy.map_rows`` is row-local by contract, so the per-block map
+    is bit-identical to slicing a whole-stream domain — zero bits change
+    — while the digitization scales with the shard count, and only the
+    narrow raw rows cross the sharding boundary, not the widened domain
+    planes.  ``to_domain=None`` keeps the legacy contract: ``values``
+    already domain-prepared (direct ``backend.run`` callers).
 
     Invariant: integer carry state is bitwise identical to the
     single-device schedule at any shard count, because the quantization
@@ -373,8 +406,7 @@ def _run_shard_map(values, segment_ids, num_segments, *, policy: Policy,
         raise ValueError(f"shard_map backend: axis_names {unknown} not in "
                          f"mesh axes {mesh.axis_names}")
     nshards = int(np.prod([mesh.shape[a] for a in axes]))
-    inner = select_local_backend(policy)
-    inner_kw = {"program": program} if inner.staged else {}
+    inner = select_local_backend(policy)       # staged: pallas | blocked
 
     n, d = values.shape
     pad = (-n) % (nshards * block_size)
@@ -386,11 +418,10 @@ def _run_shard_map(values, segment_ids, num_segments, *, policy: Policy,
     prep_state = tuple(prep_state)
 
     def shard_body(v, ids, *prep):
-        if to_domain is not None:
-            v = to_domain(v, *prep)
         carry = inner.run(v, ids, num_segments, policy=policy,
                           block_size=block_size, interpret=interpret,
-                          **inner_kw)
+                          program=program, to_domain=to_domain,
+                          prep_state=prep)
         # the merge issues immediately after the local fold, with no
         # barrier in between: one fused collective per carry dtype, free
         # to overlap the tail of the last block's update on hardware

@@ -324,17 +324,35 @@ class Policy:
         """
         return None
 
-    def to_domain(self, values: jnp.ndarray, ctx):
-        """Stage 0b: elementwise map of raw (N, D) rows into the
-        accumulation domain under a fixed ``ctx``.
+    def domain_args(self, ctx) -> Tuple:
+        """Stage 0a': the ctx as the f32 scalars ``map_rows`` reads.
 
-        Row-local by contract (no cross-row reductions), so the
-        distributed path may apply it shard-by-shard: ``to_domain`` of a
-        row slice equals the row slice of ``to_domain`` — bit for bit.
-        The domain may be wider than (N, D) — e.g. per-element digit
-        splits — as long as ``finalize`` maps the carry back to (S, D).
+        Whatever needs ``log2``, ``frexp`` or ``ldexp`` (an exponent, the
+        exact power-of-two factors of a descale) is computed here, once
+        per call and outside any kernel, so the row map itself lowers in
+        a kernel body."""
+        return ()
+
+    def map_rows(self, values: jnp.ndarray, *args):
+        """Stage 0b: elementwise map of raw (N, D) rows into the
+        accumulation domain, given ``domain_args(ctx)``.
+
+        Row-local by contract (no cross-row reductions), so an executor
+        may apply it to any row slice — a shard, one schedule block in
+        VMEM — and get that slice of the whole-stream map, bit for bit.
+        Only multiplies by exact powers of two, round-half-even, integer
+        converts, shifts and concatenation, so the pallas kernel runs it
+        per block.  The domain may be wider than (N, D) — e.g.
+        per-element digit splits — as long as ``finalize`` maps the
+        carry back to (S, D).
         """
         return values.astype(jnp.float32)
+
+    def to_domain(self, values: jnp.ndarray, ctx):
+        """Stage 0b under a fixed ``ctx``: ``map_rows`` of
+        ``domain_args(ctx)``, so the whole-stream map and every
+        executor's per-block map are one function."""
+        return self.map_rows(values, *self.domain_args(ctx))
 
     def prepare(self, values: jnp.ndarray, num_terms: int, *,
                 shared_max=None):
@@ -549,8 +567,11 @@ class ExactPolicy(Policy):
     def prepare_ctx(self, max_abs, num_terms: int):
         return choose_scale(max_abs, max(num_terms, 1))
 
-    def to_domain(self, values: jnp.ndarray, ctx):
-        return quantize(values.astype(jnp.float32), ctx)
+    def domain_args(self, ctx):
+        return (ctx,)
+
+    def map_rows(self, values: jnp.ndarray, scale):
+        return quantize(values.astype(jnp.float32), scale)
 
     def finalize(self, carry, ctx) -> jnp.ndarray:
         return dequantize(carry[0], ctx)
@@ -637,24 +658,36 @@ class Exact2Policy(Policy):
                 f"with core.intac.limb_merge3")
         return choose_scale(max_abs, 1, qbits=self.QBITS)
 
-    def to_domain(self, values: jnp.ndarray, ctx):
+    def domain_args(self, ctx):
+        # the scale, and the two exact factors of ``dequantize``'s
+        # descale by it (``prepare_ctx`` makes it a power of two)
+        scale = jnp.asarray(ctx, jnp.float32)
+        e = jnp.round(jnp.log2(jnp.maximum(scale, jnp.float32(1e-45)))) \
+            .astype(jnp.int32)
+        return (scale,) + intac.ldexp2_factors(-e)
+
+    def map_rows(self, values: jnp.ndarray, scale, inv_a, inv_b):
         v = values.astype(jnp.float32)
-        n, d = v.shape
-        scale = ctx
         q = quantize(v, scale)
-        res = v - dequantize(q, scale)        # exact: Dekker/Sterbenz
+        # ``dequantize(q, scale)`` as its two power-of-two steps.  The
+        # select changes no value (q = 0 descales to +0); it keeps the
+        # product rounded on its own, never fused into the subtract, so
+        # a descale that overflows reads inf as in ``dequantize``
+        deq = q.astype(jnp.float32) * inv_a * inv_b
+        res = v - jnp.where(q == 0, jnp.float32(0), deq)   # exact: Sterbenz
         # the residual in quantum units: |res * scale| <= 1/2, and the
         # power-of-two multiply is exact, so the digit split below loses
-        # nothing above the 49-bit window
-        digits = intac.bin_split(res * scale, 0, bits=intac.RES_BIN_BITS,
-                                 num=intac.RES_NUM_BINS)   # (NB, N, D)
+        # nothing above the 49-bit window (its anchor is the quantum:
+        # ``bin_split(res * scale, 0, ...)`` without the unit rescale)
+        digits = intac.bin_digits(res * scale, bits=intac.RES_BIN_BITS,
+                                  num=intac.RES_NUM_BINS)
         # one (N, (1+NB)*D) f32 domain: quantized part | digit planes.
         # Every column holds an integer below 2^QBITS (q) or 2^6
         # (digits), so the f32 round-trip back to int32 in ``contrib``
         # is exact and a single plane dot covers the whole domain.
-        planes = jnp.moveaxis(digits, 0, 1).reshape(
-            n, intac.RES_NUM_BINS * d)
-        return jnp.concatenate([q.astype(jnp.float32), planes], axis=1)
+        return jnp.concatenate([q.astype(jnp.float32)]
+                               + [dg.astype(jnp.float32) for dg in digits],
+                               axis=1)
 
     def contrib(self, onehot: jnp.ndarray, vals: jnp.ndarray):
         """One exact plane dot per block over the whole quantized+digits
@@ -738,11 +771,15 @@ class ProcrastinatePolicy(Policy):
                 f"stream and add the bin carries")
         return intac.bin_ref_exponent(max_abs)
 
-    def to_domain(self, values: jnp.ndarray, ctx):
-        v = values.astype(jnp.float32)
-        n, d = v.shape
-        digits = intac.bin_split(v, ctx)             # (NB, N, D)
-        return jnp.moveaxis(digits, 0, 1).reshape(n, intac.NUM_BINS * d)
+    def domain_args(self, ctx):
+        # ``bin_split``'s rescale to the window, as two exact factors
+        return intac.ldexp2_factors(-jnp.asarray(ctx, jnp.int32))
+
+    def map_rows(self, values: jnp.ndarray, inv_a, inv_b):
+        v = values.astype(jnp.float32) * inv_a * inv_b
+        # digit-major along the feature axis: [bin 0 | ... | bin NB-1]
+        return jnp.concatenate(intac.bin_digits(v, bits=intac.BIN_BITS,
+                                                num=intac.NUM_BINS), axis=1)
 
     def init(self, num_segments: int, d: int):
         # d is the (N, NB*D) domain width: the ovf counter is (S, D)

@@ -183,22 +183,34 @@ def test_blocks_per_step_sizing():
     assert depths == sorted(depths, reverse=True)
 
 
+@pytest.mark.parametrize("mapped", [False, True], ids=["domain", "raw"])
 @pytest.mark.parametrize("policy", POLICIES)
-def test_pallas_supertile_depth_is_bitwise_invisible(policy):
+def test_pallas_supertile_depth_is_bitwise_invisible(policy, mapped):
     """blocks_per_step ∈ {1, 2, 4, 8} — including depths that force
-    whole-sentinel-block padding — changes zero bits for every tier."""
+    whole-sentinel-block padding — changes zero bits for every tier; so
+    does handing the kernel raw rows to map per block (``to_domain``), at
+    an N that leaves the last block partial."""
     pol = R.get_policy(policy)
-    vals, ids = _data(768, 8, 5, seed=4)         # 6 blocks of 128
+    n = 700 if mapped else 768                   # 6 blocks of 128
+    vals, ids = _data(n, 8, 5, seed=4)
     ids = R.mask_out_of_range(ids, 5)
-    domain, ctx = pol.prepare(vals, 768)
+    domain, ctx = pol.prepare(vals, n)
+    kw = ({"to_domain": pol.map_rows, "prep_state": pol.domain_args(ctx)}
+          if mapped else {})
     outs = []
     for bps in (1, 2, 4, 8):                     # 6 % 4 != 0: pads
-        carry = segsum_policy_pallas(domain, ids, 5, policy=pol,
-                                     block_rows=128, interpret=True,
-                                     blocks_per_step=bps)
+        carry = segsum_policy_pallas(vals if mapped else domain, ids, 5,
+                                     policy=pol, block_rows=128,
+                                     interpret=True, blocks_per_step=bps,
+                                     **kw)
         outs.append(np.asarray(pol.finalize(carry, ctx)))
     for o in outs[1:]:
         assert np.array_equal(outs[0], o)
+    if mapped:
+        prepared = R.get_backend("pallas").run(domain, ids, 5, policy=pol,
+                                               block_size=128)
+        assert np.array_equal(outs[0],
+                              np.asarray(pol.finalize(prepared, ctx)))
 
 
 def test_ops_segment_sum_bps_bitwise():

@@ -20,6 +20,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro import reduce as R
+from repro.reduce import api
 
 POLICIES = ("fast", "compensated", "exact", "exact2", "procrastinate")
 
@@ -76,3 +77,26 @@ def test_serving_logprob_mean_compiles_for_v5e(one_chip):
     text = _compiled_text(f, one_chip, ((8 * 33,), jnp.float32),
                           ((8 * 33,), jnp.int32))
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("n, d, s",
+                         [(1_605_972, 128, 340), (200_704, 1024, 1)],
+                         ids=["gradsq", "global_norm_leaf"])
+def test_exact2_sumsq_maps_its_domain_in_the_kernel(one_chip, n, d, s):
+    """exact2 ``op="sumsq"`` calls as an eager ``reduce`` runs them (the
+    square, ``pre``, is a program of its own) compile to one Mosaic
+    kernel that maps each block into the 8·D domain in VMEM, and no
+    N x 8·D array is left in the program: the benchmark's
+    stablelm-1.6b-gradsq shard (1,605,972 x 128 f32 rows in 340 sets;
+    12.25 GiB of temporaries when the domain was built for the whole
+    stream first), and ``global_norm``'s rows of 1024 for the embedding
+    of stablelm-1.6b (refused for VMEM when the kernel read the
+    domain)."""
+    spec = R.ReduceSpec(op="sumsq", policy="exact2", backend="pallas",
+                        interpret=False)
+    args = [jax.ShapeDtypeStruct((n, d), jnp.float32, sharding=one_chip),
+            jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip)]
+    compiled = api._dispatch.lower(*args, spec=spec, num_segments=s,
+                                   segmented=s > 1, squeeze_d=False).compile()
+    assert compiled.as_text().count('"tpu_custom_call"') == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5 * 2 ** 30

@@ -14,12 +14,14 @@ import subprocess
 import sys
 from pathlib import Path
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro import reduce as R
 from repro.core import intac
+from repro.kernels import ops
 
 REPO = Path(__file__).resolve().parent.parent
 N = 1 << 20
@@ -104,6 +106,129 @@ def test_residual_limb_within_1ulp_on_local_backends(stream):
                       for v in intac.limbs_canonical(c[0], c[1])])
     for other in limbs[1:]:
         assert all(np.array_equal(a, b) for a, b in zip(limbs[0], other))
+
+
+def _with_dropped_rows(x: np.ndarray, s: int, seed: int = 3):
+    """Labels in [0, s) for the rows of ``x``, with 60 rows dropped (30 on
+    the sentinel, 30 past ``s``) whose payloads carry NaN, +-Inf and
+    1e38.  Returns (values, labels, number of kept rows)."""
+    rng = np.random.RandomState(seed)
+    x = x.copy()
+    ids = rng.randint(0, s, len(x)).astype(np.int32)
+    drop = rng.choice(len(x), 60, replace=False)
+    ids[drop[:30]] = R.OUT_OF_RANGE_LABEL
+    ids[drop[30:]] = s + 2
+    for k, bad in enumerate((np.nan, np.inf, -np.inf, 1e38)):
+        x[drop[k::4]] = bad
+    return x, ids, len(x) - 60
+
+
+@pytest.mark.parametrize("backend", ("ref", "blocked", "pallas"))
+@pytest.mark.parametrize("policy", ("exact", "exact2", "procrastinate",
+                                    "fast"))
+@pytest.mark.parametrize("stream", [third_stream, cancellation_stream])
+def test_block_domain_map_matches_whole_stream_prepare(stream, policy,
+                                                       backend,
+                                                       monkeypatch):
+    """The integer tiers map each schedule block into their domain as it
+    folds (in VMEM, on pallas): against the whole-stream
+    ``policy.prepare`` the schedule then folds, the carry, the result and
+    the status flags are the same bits — with dropped rows carrying NaN,
+    Inf and 1e38, N no multiple of the block, and S over one label tile
+    of the pallas carry budget.  (``fast``, whose front door maps the
+    stream, runs the per-block map here only: its float domain is where
+    a dropped row's NaN would show if the block step did not zero it.)"""
+    n, d, s, bs = 1037, 4, 9, 128
+    x, ids, kept_rows = _with_dropped_rows(stream(n * d).reshape(n, d), s)
+    pol = R.get_policy(policy)
+    # about 4 labels per pallas label tile, so S=9 takes three
+    monkeypatch.setattr(ops, "_SEGSUM_ACC_BUDGET",
+                        4 * pol.domain_width(d) * pol.carry_len)
+    vals = jnp.asarray(x)
+    mids = R.mask_out_of_range(jnp.asarray(ids), s)
+    bk = R.get_backend(backend)
+    domain, ctx = pol.prepare(
+        jnp.where((mids >= 0)[:, None], vals, 0.0), n)
+    whole = bk.run(domain, mids, s, policy=pol, block_size=bs)
+    block = bk.run(vals, mids, s, policy=pol, block_size=bs,
+                   to_domain=pol.map_rows, prep_state=pol.domain_args(ctx))
+    for a, b in zip(whole, block):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    if not pol.needs_max_stat:
+        return                     # the front door maps fast's stream
+
+    out, st = R.reduce(vals, segment_ids=jnp.asarray(ids), num_segments=s,
+                       policy=policy, backend=backend, block_size=bs,
+                       with_status=True)
+    assert np.array_equal(np.asarray(out),
+                          np.asarray(pol.finalize(whole, ctx)))
+    assert (bool(st.nonfinite), bool(st.saturated), int(st.kept_rows)) == \
+        (False, False, kept_rows)
+
+    # a NaN in a kept row trips the flag, and the result keeps the bits
+    # of the whole-stream path (the NaN's segment included)
+    x[np.flatnonzero(ids == 0)[0], 1] = np.nan
+    out, st = R.reduce(jnp.asarray(x), segment_ids=jnp.asarray(ids),
+                       num_segments=s, policy=policy, backend=backend,
+                       block_size=bs, with_status=True)
+    masked = jnp.where((mids >= 0)[:, None], jnp.asarray(x), 0.0)
+    domain, ctx = pol.prepare(masked, n)
+    want = pol.finalize(bk.run(domain, mids, s, policy=pol, block_size=bs),
+                        ctx)
+    assert np.array_equal(np.asarray(out).view(np.int32),
+                          np.asarray(want).view(np.int32))
+    assert bool(st.nonfinite) and int(st.kept_rows) == kept_rows
+
+
+def _ldexp_domain(policy: str, v, ctx):
+    """The integer tiers' domain maps written with ``ldexp``
+    (``dequantize``, ``bin_split``), as the whole stream was mapped
+    before the map moved into the block schedule."""
+    if policy == "exact2":
+        q = intac.quantize(v, ctx)
+        res = v - intac.dequantize(q, ctx)
+        digits = intac.bin_split(res * ctx, 0, bits=intac.RES_BIN_BITS,
+                                 num=intac.RES_NUM_BINS)
+        return jnp.concatenate([q.astype(jnp.float32)]
+                               + [dg.astype(jnp.float32) for dg in digits],
+                               axis=1)
+    return jnp.concatenate(list(intac.bin_split(v, ctx)), axis=1)
+
+
+def _exponent_sweep():
+    """One 64-row stream per largest binade, subnormal to the top, with
+    values spread 40 binades below it; then the edges: +-Inf, NaN, the
+    largest finite value and subnormals."""
+    rng = np.random.RandomState(0)
+    for emax in range(-149, 128):
+        e = emax - rng.randint(0, 40, size=64)
+        x = np.sign(rng.randn(64)) * np.ldexp(rng.uniform(1, 2, 64), e)
+        x[:3] = (np.ldexp(1.5, emax), 0.0, -0.0)
+        yield x.astype(np.float32)
+    big = np.finfo(np.float32).max
+    for x in ([np.inf, 1.0], [-np.inf, 1.0, np.nan], [big, -big, 1.0, 3e-30],
+              [2.0 ** -127, 2.0 ** -149, 3 * 2.0 ** -127]):
+        yield np.asarray(x, np.float32)
+
+
+@pytest.mark.parametrize("policy", ("exact2", "procrastinate"))
+def test_map_rows_matches_the_ldexp_formulation(policy):
+    """``map_rows`` multiplies by exact power-of-two factors computed
+    once (``domain_args``) where the map used ``ldexp`` per element, so
+    the kernel body can run it: the same domain bits, at every binade,
+    through overflow, Inf and NaN."""
+    pol = R.get_policy(policy)
+
+    @jax.jit
+    def both(x):
+        v = x[:, None]
+        ctx = pol.prepare_ctx(jnp.max(jnp.abs(v)), x.shape[0])
+        return pol.to_domain(v, ctx), _ldexp_domain(policy, v, ctx)
+
+    for x in _exponent_sweep():
+        got, want = both(jnp.asarray(x))
+        assert np.array_equal(np.asarray(got).view(np.int32),
+                              np.asarray(want).view(np.int32)), x
 
 
 SHARD_SNIPPET = r"""

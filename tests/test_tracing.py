@@ -127,3 +127,22 @@ def test_reduce_span_under_jit_reads_no_device_value(tmp_path):
     assert np.array_equal(np.asarray(out), np.full(3, 8.0, np.float32))
     (top,) = spans["repro.reduce"]
     assert top[2]["rows"] == 8 and top[2]["segments"] == 1
+
+
+@pytest.mark.parametrize("policy, backend, domain", [
+    ("exact2", "pallas", "block"), ("exact2", "blocked", "block"),
+    ("fast", "blocked", "stream")])
+def test_dispatch_span_says_where_the_domain_is_mapped(tmp_path, policy,
+                                                       backend, domain):
+    """The integer tiers map each block into their domain inside the
+    executor; fast maps (casts) the whole stream before it."""
+    x = jnp.arange(24.0).reshape(12, 2)
+
+    def call():
+        return repro.reduce(x, policy=policy, backend=backend)
+
+    plain = call()
+    out, spans = record(tmp_path, call)
+    assert np.array_equal(np.asarray(out), np.asarray(plain))
+    (dispatch,) = spans["repro.reduce.dispatch"]
+    assert dispatch[2] == {"domain": domain}
