@@ -199,6 +199,9 @@ def param_specs(cfg: ModelConfig, params, *, fsdp_axis: str = "data",
     specs["blocks"] = [
         _block_specs(spec, cfg, stack=True, fsdp=fsdp, cross=cfg.is_encdec)
         for spec in cfg.period]
+    if cfg.first_dense:
+        specs["lead_blocks"] = _block_specs(cfg.lead_spec, cfg, stack=True,
+                                            fsdp=fsdp, cross=cfg.is_encdec)
     specs["final_norm"] = P(None)
     if "lm_head" in params:
         specs["lm_head"] = P(fsdp, "model")

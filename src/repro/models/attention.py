@@ -19,12 +19,14 @@ Caches:
 
 from __future__ import annotations
 
+import math
 from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from .config import ModelConfig
+from .config import ModelConfig, YarnCfg
 from .layers import apply_mrope, apply_rope, causal_mask, dense, dense_init
 
 
@@ -230,6 +232,53 @@ def gqa_apply(params, x, cfg: ModelConfig, *, positions, mode: str = "train",
 # ---------------------------------------------------------------------------
 
 
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """DeepSeek-V2's ``yarn_get_mscale``: 0.1 * mscale * ln(factor) + 1."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(dim: int, base: float, y: YarnCfg) -> np.ndarray:
+    """YaRN inverse frequencies (dim/2,), float32: each frequency is
+    interpolated between the extrapolated ``1/base^(2i/dim)`` and the
+    interpolated ``1/(factor * base^(2i/dim))`` by a linear ramp between
+    the correction dimensions of ``beta_fast`` and ``beta_slow`` (the
+    published ``DeepseekV2YarnRotaryEmbedding``)."""
+    def corr_dim(rot):
+        return (dim * math.log(y.original_max_position
+                               / (rot * 2 * math.pi))) / (2 * math.log(base))
+
+    low = max(math.floor(corr_dim(y.beta_fast)), 0)
+    high = min(math.ceil(corr_dim(y.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    extra = 1.0 / base ** (np.arange(0, dim, 2) / dim)
+    inter = extra / y.factor
+    ramp = np.clip((np.arange(dim // 2) - low) / (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp                       # 1: extrapolate, 0: interpolate
+    return (inter * (1.0 - keep) + extra * keep).astype(np.float32)
+
+
+def mla_rope(cfg: ModelConfig):
+    """(inv_freq or None, cos/sin scale, softmax scale) of MLA's rope
+    part: YaRN where ``cfg.rope_scaling`` is set, plain RoPE otherwise.
+    The published code permutes each head's rope dimensions from
+    interleaved pairs to halves before rotating halves; on weights made
+    here that is a fixed permutation of the columns of ``wq``'s rope part
+    and of ``wkr``, which matters only for loading a published
+    checkpoint."""
+    sm_scale = (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
+    y = cfg.rope_scaling
+    if y is None:
+        return None, 1.0, sm_scale
+    freqs = jnp.asarray(yarn_inv_freq(cfg.qk_rope_dim, cfg.rope_theta, y))
+    scale = yarn_mscale(y.factor, y.mscale) \
+        / yarn_mscale(y.factor, y.mscale_all_dim)
+    if y.mscale_all_dim:
+        m = yarn_mscale(y.factor, y.mscale_all_dim)
+        sm_scale = sm_scale * m * m
+    return freqs, scale, sm_scale
+
+
 def mla_init(key, cfg: ModelConfig, dtype):
     d, h = cfg.d_model, cfg.n_heads
     r, nd, rd, vd = (cfg.kv_lora_rank, cfg.qk_nope_dim, cfg.qk_rope_dim,
@@ -254,16 +303,17 @@ def mla_apply(params, x, cfg: ModelConfig, *, positions, mode: str = "train",
     h = cfg.n_heads
     r, nd, rd, vd = (cfg.kv_lora_rank, cfg.qk_nope_dim, cfg.qk_rope_dim,
                      cfg.v_head_dim)
-    sm_scale = (nd + rd) ** -0.5
+    freqs, rscale, sm_scale = mla_rope(cfg)
 
     q = _split_heads(dense(params["wq"], x), h, nd + rd)   # (B,S,H,nd+rd)
     qn, qr = q[..., :nd], q[..., nd:]
-    qr = apply_rope(qr, positions, cfg.rope_theta)
+    qr = apply_rope(qr, positions, cfg.rope_theta, freqs=freqs, scale=rscale)
 
     c = rmsnorm(params["c_norm"], dense(params["wdkv"], x), cfg.norm_eps,
                 policy=cfg.norm_reduce_policy)
     kr = dense(params["wkr"], x)[:, :, None, :]             # (B,S,1,rd)
-    kr = apply_rope(kr, positions, cfg.rope_theta)[:, :, 0]  # (B,S,rd)
+    kr = apply_rope(kr, positions, cfg.rope_theta, freqs=freqs,
+                    scale=rscale)[:, :, 0]                  # (B,S,rd)
 
     if mode in ("train", "prefill"):
         kn = _split_heads(dense(params["wuk"], c), h, nd)   # (B,S,H,nd)

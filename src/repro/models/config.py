@@ -27,11 +27,36 @@ class MoECfg:
     num_shared: int = 0
     d_ff_shared: int = 0
     capacity_factor: float = 1.25
-    router_norm_topk: bool = False   # deepseek: normalize over chosen top-k
+    router_norm_topk: bool = False   # normalize over the chosen top-k
     # accuracy-tier name to route the top-k combine-weight normalization
     # denominator through repro.reduce (None = plain XLA sum, bitwise
     # identical to the pre-algebra path)
     router_norm_policy: Optional[str] = None
+    # the share of the experts this chip holds under expert parallelism:
+    # experts [held_first, held_first + held) of every MoE layer (held=0:
+    # all of them).  The router keeps all ``num_experts`` outputs; a
+    # share's layer computes its own experts' part of the result.
+    held_first: int = 0
+    held: int = 0
+
+    @property
+    def is_share(self) -> bool:
+        return self.held > 0
+
+    @property
+    def n_held(self) -> int:
+        return self.held or self.num_experts
+
+
+@dataclass(frozen=True)
+class YarnCfg:
+    """YaRN rope scaling (DeepSeek-V2's ``rope_scaling``, type "yarn")."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -75,12 +100,17 @@ class ModelConfig:
     attn_type: str = "gqa"            # 'gqa' | 'mla'
     window: Optional[int] = None      # sliding-window size (SWA)
     rope_theta: float = 1e4
+    rope_scaling: Optional[YarnCfg] = None
     mrope: bool = False               # qwen2-vl multimodal rope (3 sections)
     # MLA (deepseek-v2) dims
     kv_lora_rank: int = 0
     qk_nope_dim: int = 128
     qk_rope_dim: int = 64
     v_head_dim: int = 128
+    # leading dense layers (deepseek's first_k_dense_replace): the first
+    # ``first_dense`` of the ``n_layers`` are attn + swiglu(d_ff) blocks
+    # run before the scan over periods
+    first_dense: int = 0
     # enc-dec (seamless): encoder depth; decoder uses n_layers
     encoder_layers: int = 0
     # modality frontend stub: inputs arrive as precomputed embeddings
@@ -132,9 +162,9 @@ class ModelConfig:
     subquadratic: bool = False
 
     def __post_init__(self):
-        assert self.n_layers % len(self.period) == 0, (
-            f"{self.name}: n_layers {self.n_layers} not divisible by period "
-            f"{len(self.period)}")
+        assert (self.n_layers - self.first_dense) % len(self.period) == 0, (
+            f"{self.name}: n_layers {self.n_layers} - first_dense "
+            f"{self.first_dense} not divisible by period {len(self.period)}")
 
     @property
     def hdim(self) -> int:
@@ -146,7 +176,18 @@ class ModelConfig:
 
     @property
     def n_periods(self) -> int:
-        return self.n_layers // len(self.period)
+        return (self.n_layers - self.first_dense) // len(self.period)
+
+    @property
+    def lead_spec(self) -> BlockSpec:
+        """The block of the leading dense layers."""
+        return BlockSpec("attn", "swiglu")
+
+    @property
+    def cache_pattern(self) -> Tuple[BlockSpec, ...]:
+        """The block of each entry of ``init_caches``' list: the leading
+        dense layers' stack (if any), then each period position."""
+        return ((self.lead_spec,) if self.first_dense else ()) + self.period
 
     @property
     def is_encdec(self) -> bool:
@@ -179,10 +220,11 @@ class ModelConfig:
         def mlp_params(spec: BlockSpec):
             if spec.mlp == "moe":
                 m = self.moe
-                routed = m.num_experts * 3 * d * m.d_ff_expert
+                routed = m.n_held * 3 * d * m.d_ff_expert
                 shared = m.num_shared * 3 * d * (m.d_ff_shared or m.d_ff_expert)
                 router = d * m.num_experts
-                active = (m.top_k * 3 * d * m.d_ff_expert + shared + router)
+                active = (min(m.top_k, m.n_held) * 3 * d * m.d_ff_expert
+                          + shared + router)
                 return routed + shared + router, active
             if spec.mlp == "none":
                 return 0, 0
@@ -214,6 +256,10 @@ class ModelConfig:
             t, a = block_params(spec)
             total += t * self.n_periods
             active += a * self.n_periods
+        if self.first_dense:
+            t, a = block_params(self.lead_spec)
+            total += t * self.first_dense
+            active += a * self.first_dense
         emb = self.padded_vocab * d * (1 if self.tie_embeddings else 2)
         total += emb
         active += emb

@@ -118,13 +118,19 @@ def rope_freqs(hdim: int, theta: float) -> jnp.ndarray:
 
 
 def apply_rope(x: jnp.ndarray, positions: jnp.ndarray,
-               theta: float = 1e4) -> jnp.ndarray:
-    """x (..., S, H, hd); positions (..., S) int32."""
+               theta: float = 1e4, *, freqs=None,
+               scale: float = 1.0) -> jnp.ndarray:
+    """x (..., S, H, hd); positions (..., S) int32.  ``freqs`` (hd/2,)
+    replaces the standard grid (YaRN's interpolated one); ``scale``
+    multiplies cos and sin."""
     hd = x.shape[-1]
-    freqs = rope_freqs(hd, theta)                         # (hd/2,)
+    if freqs is None:
+        freqs = rope_freqs(hd, theta)                     # (hd/2,)
     ang = positions[..., None].astype(jnp.float32) * freqs  # (..., S, hd/2)
     ang = ang[..., None, :]                               # (..., S, 1, hd/2)
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)

@@ -6,7 +6,10 @@ period pattern are stacked with a leading ``n_periods`` axis and consumed by
 ``lax.scan``, so HLO size is O(period), not O(depth) — essential for the
 512-device dry-run compiles.  Heterogeneous stacks (Jamba 1:7, xLSTM m/s
 mix, MoE-every-k) fall out of the period pattern.  Decode carries the
-per-layer caches through the same scan.
+per-layer caches through the same scan.  Leading dense layers
+(``cfg.first_dense``, deepseek's ``first_k_dense_replace``) are a stack of
+their own, run before the periods, and their caches come first in the
+cache list (``cfg.cache_pattern``).
 """
 
 from __future__ import annotations
@@ -83,6 +86,9 @@ def init_params(key, cfg: ModelConfig) -> Dict[str, Any]:
         _stacked_block_init(keys[1 + j], spec, cfg, dtype, cfg.n_periods,
                             cross=cfg.is_encdec)
         for j, spec in enumerate(cfg.period)]
+    if cfg.first_dense:
+        params["lead_blocks"] = _stacked_block_init(
+            keys[7], cfg.lead_spec, cfg, dtype, cfg.first_dense)
     params["final_norm"] = rmsnorm_init(cfg.d_model, dtype)
     if not cfg.tie_embeddings:
         params["lm_head"] = dense_init(
@@ -119,8 +125,12 @@ def _constrain_act(x, cfg: ModelConfig):
 
 
 def _apply_block(bp, spec: BlockSpec, x, cfg: ModelConfig, *, positions,
-                 mode, cache, enc_out, moe_impl, is_causal=True):
+                 mode, cache, enc_out, moe_impl, is_causal=True,
+                 token_mask=None):
+    """(x, new cache, aux loss, routed): ``routed`` is the held-expert
+    path's per-expert pair count, else None."""
     aux = jnp.float32(0.0)
+    routed = None
     h = rmsnorm(bp["norm1"], x, cfg.norm_eps,
                 policy=cfg.norm_reduce_policy)
     new_cache = {}
@@ -171,7 +181,10 @@ def _apply_block(bp, spec: BlockSpec, x, cfg: ModelConfig, *, positions,
     if spec.mlp != "none":
         h2 = rmsnorm(bp["norm2"], x, cfg.norm_eps,
                      policy=cfg.norm_reduce_policy)
-        if spec.mlp == "moe":
+        if spec.mlp == "moe" and moe_impl == "held":
+            out, routed = moe_mod.moe_apply_held(bp["mlp"], h2, cfg,
+                                                 token_mask)
+        elif spec.mlp == "moe":
             out, a = moe_mod.moe_apply(bp["mlp"], h2, cfg, impl=moe_impl)
             aux = aux + a
         elif spec.mlp == "swiglu":
@@ -179,7 +192,7 @@ def _apply_block(bp, spec: BlockSpec, x, cfg: ModelConfig, *, positions,
         else:
             out = gelu_mlp(bp["mlp"], h2)
         x = x + out
-    return x, new_cache, aux
+    return x, new_cache, aux, routed
 
 
 # ---------------------------------------------------------------------------
@@ -189,29 +202,36 @@ def _apply_block(bp, spec: BlockSpec, x, cfg: ModelConfig, *, positions,
 
 def _run_stack(params_blocks, cfg: ModelConfig, x, *, positions, mode,
                caches, enc_out, moe_impl, remat: bool = False,
-               is_causal=True, pattern=None):
+               is_causal=True, pattern=None, token_mask=None):
     """Scan over periods. ``caches``: list per pattern position of stacked
-    cache pytrees (leading axis n_periods) or None."""
+    cache pytrees (leading axis n_periods) or None.  Returns (x, caches,
+    aux loss, routed): ``routed`` (MoE layers, held experts) int32 on the
+    held-expert path, else None."""
     pattern = pattern or cfg.period
 
     def period_body(xc, scanned):
         bps, cs = scanned
         aux = jnp.float32(0.0)
         new_cs = []
+        routed = []
         xc = _constrain_act(xc, cfg)
         # detlint: ok[DET002] aux-loss scalar chain across unrolled
         # blocks: legacy bits pinned by tests; front-door routing is the
         # knob-gated follow-up (docs/algebra.md)
         for j, spec in enumerate(pattern):
             c_j = None if cs is None else cs[j]
-            xc, nc, a = _apply_block(bps[j], spec, xc, cfg,
-                                     positions=positions, mode=mode,
-                                     cache=c_j, enc_out=enc_out,
-                                     moe_impl=moe_impl, is_causal=is_causal)
+            xc, nc, a, r = _apply_block(bps[j], spec, xc, cfg,
+                                        positions=positions, mode=mode,
+                                        cache=c_j, enc_out=enc_out,
+                                        moe_impl=moe_impl,
+                                        is_causal=is_causal,
+                                        token_mask=token_mask)
             xc = _constrain_act(xc, cfg)
             new_cs.append(nc)
             aux = aux + a
-        return xc, (tuple(new_cs), aux)
+            if r is not None:
+                routed.append(r)
+        return xc, (tuple(new_cs), aux, tuple(routed))
 
     body = period_body
     if remat:
@@ -232,12 +252,16 @@ def _run_stack(params_blocks, cfg: ModelConfig, x, *, positions, mode,
                               (tuple(params_blocks), cs_stacked))
             x, y = scan_fn(x, sl)
             ys.append(y)
-        new_caches, auxs = jax.tree.map(lambda *t: jnp.stack(t), *ys) \
-            if ys else ((), jnp.zeros((0,)))
-        return x, list(new_caches), jnp.sum(auxs)  # detlint: ok[DET001] L aux scalars
-    x, (new_caches, auxs) = jax.lax.scan(
-        scan_fn, x, (tuple(params_blocks), cs_stacked))
-    return x, list(new_caches), jnp.sum(auxs)  # detlint: ok[DET001] L aux scalars
+        new_caches, auxs, routed = jax.tree.map(
+            lambda *t: jnp.stack(t), *ys) if ys \
+            else ((), jnp.zeros((0,)), ())
+    else:
+        x, (new_caches, auxs, routed) = jax.lax.scan(
+            scan_fn, x, (tuple(params_blocks), cs_stacked))
+    # (periods, held) per MoE position -> (MoE layers, held), layer order
+    routed = jnp.stack(routed, axis=1).reshape(-1, routed[0].shape[-1]) \
+        if routed else None
+    return x, list(new_caches), jnp.sum(auxs), routed  # detlint: ok[DET001] L aux scalars
 
 
 # ---------------------------------------------------------------------------
@@ -263,7 +287,7 @@ def encode(params, cfg: ModelConfig, enc_embeds, *, remat=False):
     bsz, s, _ = enc_embeds.shape
     positions = _default_positions(cfg, bsz, s)
     enc_cfg_pattern = (BlockSpec("attn", "gelu"),)
-    x, _, _ = _run_stack([params["encoder"]["blocks"]], cfg, enc_embeds,
+    x, _, _, _ = _run_stack([params["encoder"]["blocks"]], cfg, enc_embeds,
                          positions=positions, mode="train", caches=None,
                          enc_out=None, moe_impl="capacity", remat=remat,
                          is_causal=False, pattern=enc_cfg_pattern)
@@ -274,8 +298,10 @@ def encode(params, cfg: ModelConfig, enc_embeds, *, remat=False):
 def forward_hidden(params, cfg: ModelConfig, *, tokens=None, embeds=None,
                    positions=None, mode: str = "train", caches=None,
                    enc_out=None, moe_impl: str = "capacity",
-                   remat: bool = False, position_offset=0):
-    """Backbone only: returns (final-norm hidden states, caches, aux)."""
+                   remat: bool = False, position_offset=0,
+                   token_mask=None):
+    """Backbone only: returns (final-norm hidden states, caches, aux,
+    routed); see ``forward``."""
     if embeds is not None:
         x = embeds
     else:
@@ -283,14 +309,21 @@ def forward_hidden(params, cfg: ModelConfig, *, tokens=None, embeds=None,
     bsz, s = x.shape[0], x.shape[1]
     if positions is None:
         positions = _default_positions(cfg, bsz, s, position_offset)
+    kw = dict(positions=positions, mode=mode, enc_out=enc_out,
+              moe_impl=moe_impl, remat=remat, token_mask=token_mask)
+    lead_caches = []
+    if cfg.first_dense:
+        x, lead_caches, _, _ = _run_stack(
+            [params["lead_blocks"]], cfg, x, pattern=(cfg.lead_spec,),
+            caches=None if caches is None else caches[:1], **kw)
+        caches = None if caches is None else caches[1:]
 
-    x, new_caches, aux = _run_stack(
-        params["blocks"], cfg, x, positions=positions, mode=mode,
-        caches=caches, enc_out=enc_out, moe_impl=moe_impl, remat=remat)
+    x, new_caches, aux, routed = _run_stack(params["blocks"], cfg, x,
+                                            caches=caches, **kw)
 
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps,
                 policy=cfg.norm_reduce_policy)
-    return x, new_caches, aux
+    return x, lead_caches + new_caches, aux, routed
 
 
 def _lm_head(params, cfg: ModelConfig):
@@ -300,18 +333,24 @@ def _lm_head(params, cfg: ModelConfig):
 def forward(params, cfg: ModelConfig, *, tokens=None, embeds=None,
             positions=None, mode: str = "train", caches=None,
             enc_out=None, moe_impl: str = "capacity", remat: bool = False,
-            position_offset=0, logits_pspec=None):
-    """Returns (logits, new_caches, aux_loss)."""
-    x, new_caches, aux = forward_hidden(
+            position_offset=0, logits_pspec=None, token_mask=None):
+    """Returns (logits, new_caches, aux, routed).  ``aux`` is the MoE
+    load-balance loss.  ``routed`` is None except under
+    ``moe_impl="held"`` (one chip's share of the experts), where it is
+    the (MoE layers, held experts) int32 count of the (token, choice)
+    pairs each held expert computed; ``token_mask`` (B, S) then keeps
+    the tokens that may route (padding and idle serving slots route
+    nothing)."""
+    x, new_caches, aux, routed = forward_hidden(
         params, cfg, tokens=tokens, embeds=embeds, positions=positions,
         mode=mode, caches=caches, enc_out=enc_out, moe_impl=moe_impl,
-        remat=remat, position_offset=position_offset)
+        remat=remat, position_offset=position_offset, token_mask=token_mask)
     logits = jnp.einsum("bsd,dv->bsv", x, _lm_head(params, cfg),
                         preferred_element_type=jnp.float32)
     if logits_pspec is not None:
         # keep the vocab axis sharded through the loss (26 GB/device if not)
         logits = jax.lax.with_sharding_constraint(logits, logits_pspec)
-    return logits, new_caches, aux
+    return logits, new_caches, aux, routed
 
 
 def loss_fn(params, cfg: ModelConfig, batch, *, moe_impl="capacity",
@@ -323,7 +362,7 @@ def loss_fn(params, cfg: ModelConfig, batch, *, moe_impl="capacity",
     enc_out = None
     if cfg.is_encdec:
         enc_out = encode(params, cfg, batch["enc_embeds"], remat=remat)
-    hidden, _, aux = forward_hidden(
+    hidden, _, aux, _ = forward_hidden(
         params, cfg, tokens=tokens, embeds=embeds,
         positions=batch.get("positions"), mode="train",
         enc_out=enc_out, moe_impl=moe_impl, remat=remat)
@@ -393,11 +432,12 @@ def loss_fn(params, cfg: ModelConfig, batch, *, moe_impl="capacity",
 
 def init_caches(cfg: ModelConfig, bsz: int, max_len: int,
                 dtype=None) -> list:
-    """Stacked (n_periods-leading) cache pytrees per pattern position."""
+    """Stacked (layers-leading) cache pytrees per ``cfg.cache_pattern``
+    entry: the leading dense layers' stack, then each period position."""
     dtype = dtype or jnp.dtype(cfg.dtype)
-    n = cfg.n_periods
     caches = []
-    for spec in cfg.period:
+    for i, spec in enumerate(cfg.cache_pattern):
+        n = cfg.first_dense if cfg.first_dense and i == 0 else cfg.n_periods
         if spec.kind == "attn":
             if cfg.attn_type == "mla":
                 c = attn.MLACache(
@@ -465,7 +505,8 @@ def pad_caches_to(cfg: ModelConfig, caches, max_len: int):
                 return {**c, "core": attn.MLACache(pc, pr, core.length)}
         return c
 
-    return [pad_block(c, spec) for c, spec in zip(caches, cfg.period)]
+    return [pad_block(c, spec) for c, spec in zip(caches, cfg.cache_pattern,
+                                                   strict=True)]
 
 
 def decode_step(params, cfg: ModelConfig, token, caches, position, *,
@@ -479,8 +520,8 @@ def decode_step(params, cfg: ModelConfig, token, caches, position, *,
     """
     bsz, s = token.shape[0], token.shape[1]
     positions = _default_positions(cfg, bsz, s, position)
-    logits, new_caches, _ = forward(params, cfg, tokens=token,
-                                    positions=positions, mode="decode",
-                                    caches=caches, enc_out=enc_out,
-                                    moe_impl=moe_impl)
+    logits, new_caches, _, _ = forward(params, cfg, tokens=token,
+                                       positions=positions, mode="decode",
+                                       caches=caches, enc_out=enc_out,
+                                       moe_impl=moe_impl)
     return logits, new_caches

@@ -1,6 +1,6 @@
 """Mixture-of-Experts: top-k router + capacity-based dispatch (EP-shardable).
 
-Two dispatch strategies, one contract:
+Two dispatch strategies with one contract, ``moe_apply`` -> (y, aux):
 
   * ``capacity``  — production/dry-run path: tokens are packed into a fixed
     (E, C) buffer with one-hot dispatch/combine einsums (MaxText-style).
@@ -9,6 +9,13 @@ Two dispatch strategies, one contract:
   * ``dense``     — small-scale/oracle path: every expert runs on every token,
     gated combine.  O(E) compute, exact (no capacity drops); used by smoke
     tests as the reference for the capacity path.
+
+and one of its own, ``moe_apply_held`` -> (y, routed): one chip's share of
+expert parallelism (``MoECfg.held``).  The router scores all experts, the
+(token, choice) pairs that land on the held experts run through a grouped
+matmul (``jax.lax.ragged_dot``) with no capacity and no drops, and each
+token's pairs are summed through ``combine_segsum``.  The serving engine's
+path for share configurations (``moe_impl="held"`` in ``models.forward``).
 
 The **combine** step is a segmented accumulation (each token sums its top-k
 expert contributions — variable "set" sizes once capacity drops happen);
@@ -28,10 +35,12 @@ from .layers import dense_init
 
 
 def moe_init(key, cfg: ModelConfig, dtype):
+    """A share configuration holds only its own experts' weights."""
     m = cfg.moe
     d = cfg.d_model
     v = cfg.moe_virtual_split
-    e, f = m.num_experts * v, m.d_ff_expert // v
+    assert v == 1 or not m.is_share
+    e, f = m.n_held * v, m.d_ff_expert // v
     assert m.d_ff_expert % v == 0
     ks = jax.random.split(key, 5)
     p = {"router": dense_init(ks[0], d, m.num_experts, jnp.float32),
@@ -50,7 +59,10 @@ def moe_init(key, cfg: ModelConfig, dtype):
 
 def router_topk(router_w, x, m: MoECfg):
     """Returns (weights (T,k) f32, idx (T,k) i32, aux_loss scalar)."""
-    logits = jnp.einsum("td,de->te", x.astype(jnp.float32), router_w)
+    # float32 scores at full precision (a TPU's default f32 matmul rounds
+    # its inputs to bf16, which flips near-tied experts)
+    logits = jnp.einsum("td,de->te", x.astype(jnp.float32), router_w,
+                        precision=jax.lax.Precision.HIGHEST)
     probs = jax.nn.softmax(logits, axis=-1)
     w, idx = jax.lax.top_k(probs, m.top_k)
     if m.router_norm_topk:
@@ -103,6 +115,9 @@ def moe_apply_capacity(params, x, cfg: ModelConfig, *,
     rule made explicit.
     """
     m = cfg.moe
+    if m.is_share:
+        raise ValueError("the capacity path has no expert share; a share "
+                         "configuration runs moe_impl='held'")
     b, s, d = x.shape
     t = b * s
     v = cfg.moe_virtual_split
@@ -184,17 +199,20 @@ def moe_apply_capacity(params, x, cfg: ModelConfig, *,
 
 
 def moe_apply_dense(params, x, cfg: ModelConfig):
-    """Exact O(E)-compute reference: every expert sees every token."""
+    """Exact O(E)-compute reference: every expert sees every token (every
+    held expert, for a share configuration)."""
     m = cfg.moe
     v = cfg.moe_virtual_split
     b, s, d = x.shape
     xt = x.reshape(b * s, d)
     w, idx, aux = router_topk(params["router"], xt, m)
-    e_eff = m.num_experts * v
+    e_eff = m.n_held * v
     ye = _expert_ffn(params, jnp.broadcast_to(xt, (e_eff,) + xt.shape))
     if v > 1:   # sum virtual shards back into parent experts
         ye = ye.reshape(m.num_experts, v, *ye.shape[1:]).sum(1)  # detlint: ok[DET001] v virtual shards, fixed axis order; pinned by moe tests
-    gates = jnp.zeros((b * s, m.num_experts), jnp.float32).at[
+    if m.is_share:   # pairs on experts held elsewhere fall off the end
+        idx = _held_index(idx, m)
+    gates = jnp.zeros((b * s, m.n_held), jnp.float32).at[
         jnp.arange(b * s)[:, None], idx].add(w, mode="drop")
     yt = jnp.einsum("etd,te->td", ye.astype(jnp.float32), gates)
     if m.num_shared:
@@ -220,6 +238,61 @@ def combine_segsum(expert_rows, row_token_ids, num_tokens, *, interpret=None):
     return _reduce.reduce(expert_rows, segment_ids=row_token_ids,
                           num_segments=num_tokens, backend=backend,
                           interpret=interpret)
+
+
+def _held_index(idx, m: MoECfg):
+    """Each chosen expert's index among the held ones; ``m.n_held`` for
+    an expert held elsewhere."""
+    local = idx - m.held_first
+    return jnp.where((local >= 0) & (local < m.n_held), local, m.n_held)
+
+
+def moe_apply_held(params, x, cfg: ModelConfig, token_mask=None):
+    """One chip's share of an expert-parallel layer: x (B, S, D) ->
+    (y (B, S, D), routed (n_held,) int32).
+
+    The router scores every expert (softmax, top-k by value, weights as
+    ``router_topk`` gives them).  The (token, choice) pairs whose expert
+    is held here, and whose token ``token_mask`` (B, S) keeps, are
+    ordered by expert and run through a grouped SwiGLU
+    (``jax.lax.ragged_dot``, one group per held expert), with no
+    capacity: no pair is dropped.  Each pair is weighted by its gate, the
+    pairs go back to token order, and each token's set of 0 to top_k
+    rows is summed through ``combine_segsum`` (a token with no held pair
+    gives a zero row).  The shared experts run once on every token.
+    ``routed`` counts the pairs each held expert computed.
+    """
+    m = cfg.moe
+    b, s, d = x.shape
+    t, k, n = b * s, m.top_k, m.n_held
+    xt = x.reshape(t, d)
+    w, idx, _ = router_topk(params["router"], xt, m)        # (T,k)
+    grp = _held_index(idx, m)                               # (T,k)
+    if token_mask is not None:
+        grp = jnp.where(token_mask.reshape(t, 1), grp, n)
+    grp = grp.reshape(t * k)
+    order = jnp.argsort(grp, stable=True)                   # held first
+    routed = jnp.bincount(grp, length=n + 1)[:n].astype(jnp.int32)
+    tok = order // k                                        # pair -> token
+    xs = xt[tok]
+    h = (jax.nn.silu(jax.lax.ragged_dot(
+        xs, params["wg"], routed, preferred_element_type=jnp.float32))
+        * jax.lax.ragged_dot(xs, params["wi"], routed,
+                             preferred_element_type=jnp.float32))
+    ys = jax.lax.ragged_dot(h.astype(x.dtype), params["wo"], routed,
+                            preferred_element_type=jnp.float32)
+    held = grp[order] < n
+    rows = jnp.where(held[:, None], ys * w.reshape(t * k)[order][:, None],
+                     0.0)
+    inv = jnp.argsort(order)                                # token order
+    from repro import reduce as _reduce
+    ids = jnp.where(grp < n, jnp.arange(t * k) // k,
+                    _reduce.OUT_OF_RANGE_LABEL)
+    yt = combine_segsum(rows[inv], ids, t)
+    if m.num_shared:
+        from .layers import swiglu
+        yt = yt + swiglu(params["shared"], xt).astype(jnp.float32)
+    return yt.astype(x.dtype).reshape(b, s, d), routed
 
 
 def moe_apply(params, x, cfg: ModelConfig, *, impl: str = "capacity",

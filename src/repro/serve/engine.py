@@ -35,6 +35,18 @@ Per-request accuracy plumbing goes through ``repro.reduce``:
     invariant to batch composition (serving replicas agree to the last
     bit, the property pinned by tests/test_serve.py).
 
+A configuration that holds one chip's share of the experts
+(``MoECfg.held``) runs its MoE layers on the held-expert path
+(``moe_impl="held"``): only the held experts compute, and only for the
+tokens of slots that decode (the ``active`` mask) or for a chunk's real
+prompt tokens, so idle slots and chunk padding route nothing.  Its
+programs also return the pairs routed to each held expert in each MoE
+layer, read in the same ``device_get`` as the sampled tokens (no
+transfer of its own) and handed to ``Engine.on_routing`` when it is set.
+Those programs update the caches in place (donated).
+Every other configuration runs every expert on every token
+(``moe_impl="dense"``) and its programs return nothing extra.
+
 The old all-at-once API survives as a thin wrapper: ``generate()``
 enqueues every request at time zero and drains the loop.
 """
@@ -51,7 +63,7 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 
 from repro import reduce as _reduce
-from repro.models import decode_step, forward, init_caches, pad_caches_to
+from repro.models import forward, init_caches, pad_caches_to
 from repro.models.config import ModelConfig
 
 from .kv_pool import PagedKVPool
@@ -117,16 +129,29 @@ class Engine:
         self._lp_vals: List[np.ndarray] = []
         self._lp_ids: List[np.ndarray] = []
 
+        held = cfg.moe is not None and cfg.moe.is_share
+        impl = "held" if held else "dense"
+        #: called as ``on_routing(engine step, "prefill" | "decode",
+        #: counts)`` with each program's (MoE layers, held experts) count
+        #: of routed pairs as it is read (held-expert path only)
+        self.on_routing: Optional[Callable] = None
+        self._pending: List[tuple] = []       # counts not yet read
+        self._span_routed: List[np.ndarray] = []
+
+        # Both programs return (logits, caches, routed); ``routed`` is the
+        # held-expert path's count, else None (no output).
         def _decode_fn(params, tok, caches, pos, active):
-            logits, new_caches = decode_step(params, cfg, tok, caches, pos,
-                                             moe_impl="dense")
+            logits, new_caches, _, routed = forward(
+                params, cfg, tokens=tok, mode="decode", caches=caches,
+                position_offset=pos, moe_impl=impl,
+                token_mask=active[:, None])
             # freeze idle / mid-prefill slots: their rows' garbage writes
             # (token 0 at position 0) and length bumps must not stick
             def keep(new, old):
                 sel = active.reshape((1, -1) + (1,) * (new.ndim - 2))
                 return jnp.where(sel, new, old)
             new_caches = jax.tree.map(keep, new_caches, caches)
-            return logits, new_caches
+            return logits, new_caches, routed
 
         def _with_length(caches, value):
             out = []
@@ -146,10 +171,10 @@ class Engine:
                 lambda l: jax.lax.dynamic_slice_in_dim(l, slot, 1, axis=1),
                 caches)
             sub = _with_length(sub, start)
-            logits, new_sub, _ = forward(params, cfg, tokens=toks,
-                                         mode="decode", caches=sub,
-                                         moe_impl="dense",
-                                         position_offset=start)
+            mask = (jnp.arange(toks.shape[1]) < n_valid)[None, :]
+            logits, new_sub, _, routed = forward(   # padding routes nothing
+                params, cfg, tokens=toks, mode="decode", caches=sub,
+                moe_impl=impl, position_offset=start, token_mask=mask)
             new_sub = _with_length(new_sub, start + n_valid)
             caches = jax.tree.map(
                 lambda full, one: jax.lax.dynamic_update_slice_in_dim(
@@ -157,13 +182,13 @@ class Engine:
                 caches, new_sub)
             last = jax.lax.dynamic_slice_in_dim(logits, n_valid - 1, 1,
                                                 axis=1)
-            return last, caches
+            return last, caches, routed
 
         def _classic_prefill_fn(params, caches, slot, toks):
             # whole-prompt fallback (SSM / sliding-window archs): standard
             # prefill at B=1, pad to max_len, splice into the slot
-            logits, new_sub, _ = forward(params, cfg, tokens=toks,
-                                         mode="prefill", moe_impl="dense")
+            logits, new_sub, _, _ = forward(params, cfg, tokens=toks,
+                                            mode="prefill", moe_impl=impl)
             new_sub = pad_caches_to(cfg, new_sub, self.max_len)
             caches = jax.tree.map(
                 lambda full, one: jax.lax.dynamic_update_slice_in_dim(
@@ -188,8 +213,15 @@ class Engine:
             lp = jnp.take_along_axis(logp, tok[:, None], axis=-1)[:, 0]
             return tok.astype(jnp.int32), lp.astype(jnp.float32)
 
-        self._decode = jax.jit(_decode_fn)
-        self._prefill_chunk = jax.jit(_prefill_chunk_fn)
+        # A share configuration's programs update the caches in place
+        # (donated): the chip holds one copy of them between programs,
+        # not one per program in flight.  The dense configurations keep
+        # their copying programs until donation is measured on them
+        # (ROADMAP Speed 3).
+        self._decode = jax.jit(_decode_fn,
+                               donate_argnums=(2,) if held else ())
+        self._prefill_chunk = jax.jit(_prefill_chunk_fn,
+                                      donate_argnums=(1,) if held else ())
         self._classic_prefill = jax.jit(_classic_prefill_fn)
         self._sample = jax.jit(_sample_fn)
 
@@ -230,8 +262,10 @@ class Engine:
         spans, ``repro.engine.admit``, ``.prefill`` and ``.decode``;
         ``repro.engine.sync`` marks each wait for sampled tokens, and
         ``repro.engine.first_token`` each request's first token (stats
-        ``queue_ms``, ``prefill_ms``, ``chunks``).  See docs/serving.md
-        "Measuring it"."""
+        ``queue_ms``, ``prefill_ms``, ``chunks``).  On the held-expert
+        path ``.prefill`` and ``.decode`` also carry ``routed_pairs`` and
+        ``experts_hit`` over the routing counts read inside them.  See
+        docs/serving.md "Measuring it"."""
         sched = self.scheduler
         self._clock = 0
         self._rid_base = sched._next_deliver
@@ -242,10 +276,12 @@ class Engine:
                 with TraceAnnotation("repro.engine.admit"):
                     sched.advance(self._clock)
                     admitted = sched.admit()
-                with TraceAnnotation("repro.engine.prefill"):
+                with TraceAnnotation("repro.engine.prefill") as span:
                     chunks = self._prefill_work()
-                with TraceAnnotation("repro.engine.decode"):
+                    self._routing_stats(span)
+                with TraceAnnotation("repro.engine.decode") as span:
                     slots = self._decode_work()
+                    self._routing_stats(span)
                 delivered.extend(sched.pop_ready())
                 if step.is_enabled():
                     step.set_metadata(prefill_chunks=chunks,
@@ -261,6 +297,9 @@ class Engine:
                     "admission deadlock: queued requests cannot be "
                     "admitted and no slot is active")
             self._clock += 1
+        if self._pending:
+            self._fetch()
+        self._span_routed = []
         self._finalize_logprobs(delivered)
         return delivered
 
@@ -315,10 +354,12 @@ class Engine:
                 n_valid = len(piece)
                 toks = np.zeros((1, chunk), np.int32)
                 toks[0, :n_valid] = piece
-                logits, self._caches = self._prefill_chunk(
+                logits, self._caches, routed = self._prefill_chunk(
                     self.params, self._caches, jnp.int32(tr.slot),
                     jnp.asarray(toks), jnp.int32(start),
                     jnp.int32(n_valid))
+                if routed is not None:
+                    self._pending.append((self._clock, "prefill", routed))
                 tr.prefill_pos = start + n_valid
                 if tr.prefill_pos < len(prompt):
                     continue                      # more chunks to stream
@@ -351,8 +392,8 @@ class Engine:
             jnp.asarray([0], jnp.int32),
             jnp.asarray([max(req.temperature, 0.0)], jnp.float32))
         with TraceAnnotation("repro.engine.sync"):
-            t = int(np.asarray(tok)[0])
-            lp_np = np.asarray(lp, np.float32)
+            tok_np, lp_np = self._fetch(tok, lp)
+            t = int(tok_np[0])
         self._lp_vals.append(lp_np)
         self._lp_ids.append(np.asarray([tr.rid - self._rid_base], np.int32))
         tr.out = list(req.prompt) + [t]
@@ -386,15 +427,16 @@ class Engine:
             custom[s], idv[s] = self._key_id(tr)
             steps[s] = tr.new_tokens
             temps[s] = max(tr.request.temperature, 0.0)
-        logits, self._caches = self._decode(
-            self.params, jnp.asarray(toks), self._caches,
-            jnp.asarray(pos), jnp.asarray(active))
+        logits, self._caches, routed = self._decode(
+            self.params, jnp.asarray(toks), self._caches, jnp.asarray(pos),
+            jnp.asarray(active))
+        if routed is not None:
+            self._pending.append((self._clock, "decode", routed))
         tok, lp = self._sample(self._base_key, logits,
                                jnp.asarray(custom), jnp.asarray(idv),
                                jnp.asarray(steps), jnp.asarray(temps))
         with TraceAnnotation("repro.engine.sync"):
-            tok_np = np.asarray(tok)
-            lp_np = np.asarray(lp, np.float32)
+            tok_np, lp_np = self._fetch(tok, lp)
         ids = np.full(b, _reduce.OUT_OF_RANGE_LABEL, np.int32)
         for tr in dec:
             ids[tr.slot] = tr.rid - self._rid_base
@@ -407,6 +449,26 @@ class Engine:
             tr.new_tokens += 1
             self._maybe_retire(tr, t)
         return len(dec)
+
+    def _fetch(self, *arrays):
+        """``arrays`` on the host, and every pending routing count with
+        them, in one ``device_get``."""
+        pending, self._pending = self._pending, []
+        got, counts = jax.device_get((arrays, [c for _, _, c in pending]))
+        for (clock, phase, _), c in zip(pending, counts):
+            if self.on_routing is not None:
+                self.on_routing(clock, phase, c)
+            self._span_routed.append(c)
+        return got
+
+    def _routing_stats(self, span) -> None:
+        """The routing counts read inside ``span`` as its stats: pairs
+        routed, and (layer, held expert) pairs with at least one."""
+        if self._span_routed and span.is_enabled():
+            counts = np.stack(self._span_routed)
+            span.set_metadata(routed_pairs=int(np.sum(counts)),  # detlint: ok[DET001] host int32 counts: exact
+                              experts_hit=int(np.count_nonzero(counts)))
+        self._span_routed = []
 
     def _maybe_retire(self, tr: TrackedRequest, last_tok: int) -> None:
         req = tr.request

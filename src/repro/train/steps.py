@@ -115,7 +115,7 @@ def make_prefill_step(cfg: ModelConfig, *, moe_impl: str = "capacity"):
         enc_out = None
         if cfg.is_encdec:
             enc_out = encode(params, cfg, batch["enc_embeds"])
-        logits, caches, _ = forward(
+        logits, caches, _, _ = forward(
             params, cfg, tokens=batch.get("tokens"),
             embeds=batch.get("embeds"), positions=batch.get("positions"),
             mode="prefill", enc_out=enc_out, moe_impl=moe_impl)

@@ -288,10 +288,10 @@ def test_model_forward_with_knobs_on_deterministic_and_close():
     params = init_params(jax.random.PRNGKey(0), cfg)
     tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0,
                                 cfg.vocab)
-    base, _, _ = forward(params, cfg, tokens=tokens, mode="train")
+    base, _, _, _ = forward(params, cfg, tokens=tokens, mode="train")
     cfg_on = dataclasses.replace(cfg, norm_reduce_policy="exact2")
-    on1, _, _ = forward(params, cfg_on, tokens=tokens, mode="train")
-    on2, _, _ = forward(params, cfg_on, tokens=tokens, mode="train")
+    on1, _, _, _ = forward(params, cfg_on, tokens=tokens, mode="train")
+    on2, _, _, _ = forward(params, cfg_on, tokens=tokens, mode="train")
     assert np.array_equal(np.asarray(on1, np.float32),
                           np.asarray(on2, np.float32))
     np.testing.assert_allclose(np.asarray(on1, np.float32),

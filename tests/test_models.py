@@ -87,10 +87,11 @@ def test_decode_matches_train(arch):
     if cfg.is_encdec:
         enc = jax.random.normal(KEY, (B, 16, cfg.d_model), jnp.float32)
         enc_out = encode(params, cfg, enc)
-    full, _, _ = forward(params, cfg, tokens=toks, mode="train",
-                         enc_out=enc_out, moe_impl="dense")
-    _, caches, _ = forward(params, cfg, tokens=toks[:, :S], mode="prefill",
-                           enc_out=enc_out, moe_impl="dense")
+    full, _, _, _ = forward(params, cfg, tokens=toks, mode="train",
+                            enc_out=enc_out, moe_impl="dense")
+    _, caches, _, _ = forward(params, cfg, tokens=toks[:, :S],
+                              mode="prefill", enc_out=enc_out,
+                              moe_impl="dense")
     caches = pad_caches_to(cfg, caches, 32)
     dec, _ = decode_step(params, cfg, toks[:, S:S + 1], caches, S,
                          enc_out=enc_out, moe_impl="dense")
@@ -107,8 +108,8 @@ def test_vlm_decode_with_tokens():
     embeds = jax.random.normal(KEY, (B, S, cfg.d_model), jnp.float32)
     pos = jnp.broadcast_to(jnp.arange(S)[None, :, None],
                            (B, S, 3)).astype(jnp.int32)
-    _, caches, _ = forward(params, cfg, embeds=embeds, positions=pos,
-                           mode="prefill", moe_impl="dense")
+    _, caches, _, _ = forward(params, cfg, embeds=embeds, positions=pos,
+                              mode="prefill", moe_impl="dense")
     caches = pad_caches_to(cfg, caches, 32)
     tok = jax.random.randint(KEY, (B, 1), 0, cfg.vocab)
     logits, caches2 = decode_step(params, cfg, tok, caches, S,
@@ -146,10 +147,10 @@ def test_chunked_attention_matches_full():
         cfg = get_smoke_config(arch)
         p = init_params(KEY, cfg)
         toks = jax.random.randint(KEY, (2, 64), 0, cfg.vocab)
-        l1, _, _ = forward(p, cfg.scaled(attn_qchunk=4096), tokens=toks,
-                           moe_impl="dense")
-        l2, _, _ = forward(p, cfg.scaled(attn_qchunk=8), tokens=toks,
-                           moe_impl="dense")
+        l1, _, _, _ = forward(p, cfg.scaled(attn_qchunk=4096),
+                              tokens=toks, moe_impl="dense")
+        l2, _, _, _ = forward(p, cfg.scaled(attn_qchunk=8), tokens=toks,
+                              moe_impl="dense")
         np.testing.assert_allclose(np.asarray(l1), np.asarray(l2),
                                    atol=2e-3)
 
@@ -163,9 +164,10 @@ def test_swa_ring_cache_long_decode():
     toks = jax.random.randint(jax.random.PRNGKey(3), (B, S + 4), 0,
                               cfg.vocab)
     # reference: full forward logits at each position
-    full, _, _ = forward(p, cfg, tokens=toks, mode="train", moe_impl="dense")
-    _, caches, _ = forward(p, cfg, tokens=toks[:, :S], mode="prefill",
-                           moe_impl="dense")
+    full, _, _, _ = forward(p, cfg, tokens=toks, mode="train",
+                            moe_impl="dense")
+    _, caches, _, _ = forward(p, cfg, tokens=toks[:, :S], mode="prefill",
+                              moe_impl="dense")
     assert caches[0]["core"].k.shape[2] == cfg.window      # ring-sized
     pos = S
     for i in range(4):
