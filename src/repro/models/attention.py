@@ -126,11 +126,92 @@ def _sdpa(q, k, v, mask, sm_scale):
     return out.reshape(b, s, h, hd)
 
 
+class CacheAppend(NamedTuple):
+    """What one decode step of an attention layer adds to its cache:
+    the new rows of each cache array (B, s, ...), in the cache's field
+    order; the sequence index of each (B, s), past the cache for a token
+    that enters nothing; and the new length (B,).  ``write_appended``
+    puts a stack of them into the stacked cache."""
+    rows: Tuple[jnp.ndarray, ...]
+    at: jnp.ndarray
+    length: jnp.ndarray
+
+
+def _with_rows(old, new, at):
+    """``old`` (N, T, ...) with ``new[:, q]`` (N, s, ...) at sequence
+    index ``at[:, q]`` (N, s).  An index outside [0, T) puts nothing in;
+    a later q wins a repeated index.  A select over the sequence axis,
+    which the compiler fuses into whatever reads the result."""
+    t = jnp.arange(old.shape[1], dtype=at.dtype)
+    tail = (1,) * (old.ndim - 2)
+    for q in range(at.shape[1]):
+        hit = (at[:, q, None] == t).reshape(at.shape[:1] + t.shape + tail)
+        old = jnp.where(hit, new[:, q:q + 1], old)
+    return old
+
+
+def _append(arrays, rows, at, token_mask, length):
+    """This step's cache as the layer's attention reads it, and what the
+    step appends.  ``rows[i]`` (B, s, ...) go into ``arrays[i]``
+    (B, T, ...) at the sequence indices ``at`` (B, s).  A token that
+    ``token_mask`` (B, s) leaves out — an idle serving slot, a chunk's
+    padding — gets index T, past the cache, so neither its row nor the
+    slot's length moves.  The mask leaves out a suffix of each row."""
+    b, s = at.shape
+    grown = s
+    if token_mask is not None:
+        at = jnp.where(token_mask, at, arrays[0].shape[1])
+        grown = jnp.count_nonzero(token_mask, axis=1).astype(at.dtype)
+    read = tuple(_with_rows(a, r, at) for a, r in zip(arrays, rows))
+    return read, CacheAppend(tuple(rows), at, length + grown)
+
+
+def write_appended(cfg: ModelConfig, cache, app: CacheAppend):
+    """A stack's cache (L, B, T, ...) with its layers' appends (L, B,
+    s, ...) written in, and their lengths.  Each batch row takes one
+    read-modify-write of the 128-aligned window of the sequence axis
+    that holds its new rows, across every layer, in a loop over the
+    rows; a row that appends nothing writes its window back unchanged.  The TPU keeps a cache whose rows are narrower than its 128
+    lanes with the sequence axis minor-most, and a scatter or a one-row
+    update into it makes XLA relayout the whole cache first; a window of
+    whole lanes is updated where it lies, so a donated cache is written
+    in place.  The layers of a stack hold the same tokens, so the window
+    comes from the first layer's indices.  An extend (s > 1) of the ring
+    cache can wrap round it, so it takes the whole ring."""
+    arrays = tuple(cache)[:-1]
+    t = arrays[0].shape[2]
+    s = app.at.shape[-1]
+    w = t if (cfg.window is not None and s > 1) \
+        else min(t, -(-(s + 127) // 128) * 128)
+    first = jnp.minimum(app.at[0, :, 0], t - 1)
+    starts = jnp.minimum(first // 128 * 128, t - w)
+
+    def row(arrays, args):
+        b, st = args
+        idx = jax.lax.dynamic_index_in_dim(app.at, b, 1,
+                                           keepdims=False) - st
+        out = []
+        for a, r in zip(arrays, app.rows):
+            corner = (0, b, st) + (0,) * (a.ndim - 3)
+            win = jax.lax.dynamic_slice(a, corner,
+                                        (a.shape[0], 1, w) + a.shape[3:])
+            new = jax.lax.dynamic_index_in_dim(r, b, 1, keepdims=False)
+            win = _with_rows(win[:, 0], new, idx)[:, None]
+            out.append(jax.lax.dynamic_update_slice(a, win, corner))
+        return tuple(out), None
+
+    arrays, _ = jax.lax.scan(
+        row, arrays, (jnp.arange(arrays[0].shape[1]), starts))
+    return type(cache)(*arrays, app.length)
+
+
 def gqa_apply(params, x, cfg: ModelConfig, *, positions, mode: str = "train",
               cache: Optional[KVCache] = None,
               kv_override: Optional[Tuple[jnp.ndarray, jnp.ndarray]] = None,
-              cross: bool = False, causal: bool = True):
-    """Returns (out, new_cache)."""
+              cross: bool = False, causal: bool = True, token_mask=None):
+    """Returns (out, new_cache).  In decode, ``new_cache`` is the
+    step's ``CacheAppend``, and ``token_mask`` (B, s) bool marks the
+    tokens that enter the cache (see ``_append``)."""
     b, s, d = x.shape
     h, kvh, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hdim
     sm_scale = hd ** -0.5
@@ -184,34 +265,35 @@ def gqa_apply(params, x, cfg: ModelConfig, *, positions, mode: str = "train",
     elif mode == "decode":
         # Decode/extend against a cache.  Each batch row appends its ``s``
         # new tokens at its OWN ``length[row]`` (continuous-batching slots
-        # hold requests at heterogeneous positions), so writes are per-row
-        # scatters, not one shared dynamic_update_slice.  s == 1 is the
-        # classic decode step; s > 1 is a chunked-prefill extend: the chunk
-        # attends causally to [0, length + qi] per chunk-local query qi.
-        # Out-of-bounds positions (an idle serving slot past max_len) are
-        # dropped rather than clamped.
+        # hold requests at heterogeneous positions): attention reads the
+        # cache with them selected in, and the layer returns them as a
+        # ``CacheAppend`` that the stack writes after its layer scan
+        # (``write_appended``).  s == 1 is the classic decode step; s > 1
+        # is a chunked-prefill extend: the chunk attends causally to
+        # [0, length + qi] per chunk-local query qi.  Out-of-bounds
+        # positions (an idle serving slot past max_len) are dropped
+        # rather than clamped, and so are masked tokens.
         if cache is None:
             raise ValueError("gqa_apply: mode='decode' needs a cache")
         length = cache.length                    # (B,) tokens already cached
-        rows = jnp.arange(b)[:, None]            # (B, 1)
         qi = jnp.arange(s, dtype=length.dtype)   # chunk-local query offsets
         newpos = length[:, None] + qi[None, :]   # (B, s) absolute positions
         if is_ring:
             # Ring (sliding-window) cache: slot j holds the latest absolute
             # position p <= L with p % W == j  =>  p = L - ((L - j) % W).
             w = cache.k.shape[1]
-            ck = cache.k.at[rows, newpos % w].set(k, mode="drop")
-            cv = cache.v.at[rows, newpos % w].set(v, mode="drop")
+            (ck, cv), new_cache = _append((cache.k, cache.v), (k, v),
+                                          newpos % w, token_mask, length)
             j = jnp.arange(w)[None, :]
-            last = length[:, None] + (s - 1)
+            last = new_cache.length[:, None] - 1
             pos_k = last - ((last - j) % w)                  # (B, W)
             # query qi sees ring positions in (newpos - w, newpos]
             valid = (pos_k[:, None, :] <= newpos[..., None]) \
                 & (pos_k[:, None, :] > newpos[..., None] - w) \
                 & (pos_k[:, None, :] >= 0)
         else:
-            ck = cache.k.at[rows, newpos].set(k, mode="drop")
-            cv = cache.v.at[rows, newpos].set(v, mode="drop")
+            (ck, cv), new_cache = _append((cache.k, cache.v), (k, v),
+                                          newpos, token_mask, length)
             t = ck.shape[1]
             j = jnp.arange(t)[None, None, :]
             valid = j <= newpos[..., None]                   # (B, s, T)
@@ -219,7 +301,6 @@ def gqa_apply(params, x, cfg: ModelConfig, *, positions, mode: str = "train",
                 valid &= j > (newpos[..., None] - cfg.window)
         mask = jnp.where(valid, 0.0, -1e30)               # (B, s, T)
         out = _sdpa(q, ck, cv, mask, sm_scale)
-        new_cache = KVCache(ck, cv, length + s)
     else:
         raise ValueError(mode)
 
@@ -294,9 +375,12 @@ def mla_init(key, cfg: ModelConfig, dtype):
 
 
 def mla_apply(params, x, cfg: ModelConfig, *, positions, mode: str = "train",
-              cache: Optional[MLACache] = None):
+              cache: Optional[MLACache] = None, token_mask=None):
     """Returns (out, new_cache). Decode runs fully absorbed in the latent
-    space — the cache stores only (c_kv, k_rope): r+rd floats per token."""
+    space — the cache stores only (c_kv, k_rope): r+rd floats per token.
+    In decode, ``new_cache`` is the step's ``CacheAppend``, and
+    ``token_mask`` (B, s) marks the tokens that enter the cache (see
+    ``_append``)."""
     from .layers import rmsnorm  # local import to avoid cycle at module load
 
     b, s, d = x.shape
@@ -362,10 +446,9 @@ def mla_apply(params, x, cfg: ModelConfig, *, positions, mode: str = "train",
         if cache is None:
             raise ValueError("mla_apply: mode='decode' needs a cache")
         length = cache.length
-        rows = jnp.arange(b)[:, None]
         newpos = length[:, None] + jnp.arange(s, dtype=length.dtype)[None, :]
-        cc = cache.c_kv.at[rows, newpos].set(c, mode="drop")
-        ckr = cache.k_rope.at[rows, newpos].set(kr, mode="drop")
+        (cc, ckr), new_cache = _append((cache.c_kv, cache.k_rope), (c, kr),
+                                       newpos, token_mask, length)
         t = cc.shape[1]
         # absorb W_uk into the query: q_eff (B,s,H,r)
         wuk = params["wuk"].reshape(r, h, nd)
@@ -381,7 +464,6 @@ def mla_apply(params, x, cfg: ModelConfig, *, positions, mode: str = "train",
         o_lat = jnp.einsum("bhst,btr->bshr", p, cc.astype(jnp.float32))
         wuv = params["wuv"].reshape(r, h, vd)
         out = jnp.einsum("bshr,rhd->bshd", o_lat, wuv.astype(jnp.float32))
-        new_cache = MLACache(cc, ckr, length + s)
     else:
         raise ValueError(mode)
 

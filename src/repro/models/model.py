@@ -5,8 +5,9 @@ Layer stacking is scan-over-periods: parameters for each position in the
 period pattern are stacked with a leading ``n_periods`` axis and consumed by
 ``lax.scan``, so HLO size is O(period), not O(depth) — essential for the
 512-device dry-run compiles.  Heterogeneous stacks (Jamba 1:7, xLSTM m/s
-mix, MoE-every-k) fall out of the period pattern.  Decode carries the
-per-layer caches through the same scan.  Leading dense layers
+mix, MoE-every-k) fall out of the period pattern.  Decode reads the
+per-layer caches through the same scan and writes each attention
+layer's new rows into them after it.  Leading dense layers
 (``cfg.first_dense``, deepseek's ``first_k_dense_replace``) are a stack of
 their own, run before the periods, and their caches come first in the
 cache list (``cfg.cache_pattern``).
@@ -139,11 +140,12 @@ def _apply_block(bp, spec: BlockSpec, x, cfg: ModelConfig, *, positions,
     if spec.kind == "attn":
         if cfg.attn_type == "mla":
             out, c2 = attn.mla_apply(bp["core"], h, cfg, positions=positions,
-                                     mode=mode, cache=core_cache)
+                                     mode=mode, cache=core_cache,
+                                     token_mask=token_mask)
         else:
             out, c2 = attn.gqa_apply(bp["core"], h, cfg, positions=positions,
                                      mode=mode, cache=core_cache,
-                                     causal=is_causal)
+                                     causal=is_causal, token_mask=token_mask)
         new_cache["core"] = c2
     elif spec.kind == "mamba":
         out, c2 = ssm.mamba_apply(bp["core"], h, cfg.mamba or MambaCfg(),
@@ -206,7 +208,10 @@ def _run_stack(params_blocks, cfg: ModelConfig, x, *, positions, mode,
     """Scan over periods. ``caches``: list per pattern position of stacked
     cache pytrees (leading axis n_periods) or None.  Returns (x, caches,
     aux loss, routed): ``routed`` (MoE layers, held experts) int32 on the
-    held-expert path, else None."""
+    held-expert path, else None.  In decode the scan reads the caches
+    and returns only what each attention layer appends; the rows are
+    written into the stacked caches after it, so no layer's cache is
+    copied to take one row."""
     pattern = pattern or cfg.period
 
     def period_body(xc, scanned):
@@ -261,7 +266,20 @@ def _run_stack(params_blocks, cfg: ModelConfig, x, *, positions, mode,
     # (periods, held) per MoE position -> (MoE layers, held), layer order
     routed = jnp.stack(routed, axis=1).reshape(-1, routed[0].shape[-1]) \
         if routed else None
-    return x, list(new_caches), jnp.sum(auxs), routed  # detlint: ok[DET001] L aux scalars
+    new_caches = [_write_appends(cfg, c, n) for c, n in
+                  zip(caches, new_caches, strict=True)] \
+        if caches is not None else list(new_caches)
+    return x, new_caches, jnp.sum(auxs), routed  # detlint: ok[DET001] L aux scalars
+
+
+def _write_appends(cfg: ModelConfig, cache, new):
+    """A decode step's stacked cache: an attention stack's appended rows
+    written into its cache (``attn.write_appended``, in place where the
+    cache is donated), a recurrent state as the scan returned it."""
+    if isinstance(new["core"], attn.CacheAppend):
+        return {**new, "core": attn.write_appended(cfg, cache["core"],
+                                                   new["core"])}
+    return new
 
 
 # ---------------------------------------------------------------------------

@@ -43,9 +43,18 @@ prompt tokens, so idle slots and chunk padding route nothing.  Its
 programs also return the pairs routed to each held expert in each MoE
 layer, read in the same ``device_get`` as the sampled tokens (no
 transfer of its own) and handed to ``Engine.on_routing`` when it is set.
-Those programs update the caches in place (donated).
 Every other configuration runs every expert on every token
 (``moe_impl="dense"``) and its programs return nothing extra.
+
+Cache updates (docs/serving.md "The cache-update contract"): the
+attention caches take only the rows of the tokens the mask keeps — an
+idle slot's row and a chunk's padding go to an index past the cache and
+enter nothing — and each slot's length grows by its kept tokens, so the
+decode returns them as they come out.  Only recurrent
+states (Mamba, mLSTM, sLSTM), which have no row to drop, are kept for
+idle slots by a select.  The decode takes the caches donated (updated
+in place) for every configuration; the prefill chunk does so on the
+held-expert path only.
 
 The old all-at-once API survives as a thin wrapper: ``generate()``
 enqueues every request at time zero and drains the loop.
@@ -64,6 +73,7 @@ from jax.profiler import TraceAnnotation
 
 from repro import reduce as _reduce
 from repro.models import forward, init_caches, pad_caches_to
+from repro.models.attention import KVCache, MLACache
 from repro.models.config import ModelConfig
 
 from .kv_pool import PagedKVPool
@@ -145,12 +155,15 @@ class Engine:
                 params, cfg, tokens=tok, mode="decode", caches=caches,
                 position_offset=pos, moe_impl=impl,
                 token_mask=active[:, None])
-            # freeze idle / mid-prefill slots: their rows' garbage writes
-            # (token 0 at position 0) and length bumps must not stick
+            # idle / mid-prefill slots wrote no attention row (the mask);
+            # a recurrent state has no row to drop, so keep theirs
             def keep(new, old):
                 sel = active.reshape((1, -1) + (1,) * (new.ndim - 2))
                 return jnp.where(sel, new, old)
-            new_caches = jax.tree.map(keep, new_caches, caches)
+            new_caches = [
+                n if isinstance(n["core"], (KVCache, MLACache))
+                else jax.tree.map(keep, n, o)
+                for n, o in zip(new_caches, caches, strict=True)]
             return logits, new_caches, routed
 
         def _with_length(caches, value):
@@ -165,17 +178,17 @@ class Engine:
 
         def _prefill_chunk_fn(params, caches, slot, toks, start, n_valid):
             # one prompt chunk for one slot: slice the slot's cache view,
-            # extend it with the chunk (pad tokens write past n_valid and
-            # are rolled back via the length repair), splice it back
+            # extend it with the chunk's real tokens (the padding neither
+            # writes a row nor routes, so the length comes out
+            # start + n_valid), splice it back
             sub = jax.tree.map(
                 lambda l: jax.lax.dynamic_slice_in_dim(l, slot, 1, axis=1),
                 caches)
             sub = _with_length(sub, start)
             mask = (jnp.arange(toks.shape[1]) < n_valid)[None, :]
-            logits, new_sub, _, routed = forward(   # padding routes nothing
+            logits, new_sub, _, routed = forward(
                 params, cfg, tokens=toks, mode="decode", caches=sub,
                 moe_impl=impl, position_offset=start, token_mask=mask)
-            new_sub = _with_length(new_sub, start + n_valid)
             caches = jax.tree.map(
                 lambda full, one: jax.lax.dynamic_update_slice_in_dim(
                     full, one, slot, axis=1),
@@ -213,13 +226,11 @@ class Engine:
             lp = jnp.take_along_axis(logp, tok[:, None], axis=-1)[:, 0]
             return tok.astype(jnp.int32), lp.astype(jnp.float32)
 
-        # A share configuration's programs update the caches in place
-        # (donated): the chip holds one copy of them between programs,
-        # not one per program in flight.  The dense configurations keep
-        # their copying programs until donation is measured on them
-        # (ROADMAP Speed 3).
-        self._decode = jax.jit(_decode_fn,
-                               donate_argnums=(2,) if held else ())
+        # The decode updates the caches in place (donated): the chip
+        # holds one copy of them between programs, not one per program
+        # in flight.  The prefill chunk does so on the held-expert path;
+        # the dense configurations' chunk still copies (ROADMAP Speed 3).
+        self._decode = jax.jit(_decode_fn, donate_argnums=(2,))
         self._prefill_chunk = jax.jit(_prefill_chunk_fn,
                                       donate_argnums=(1,) if held else ())
         self._classic_prefill = jax.jit(_classic_prefill_fn)

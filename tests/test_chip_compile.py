@@ -13,6 +13,7 @@ worker imports this file.  Keep these tests in this one file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -100,3 +101,38 @@ def test_exact2_sumsq_maps_its_domain_in_the_kernel(one_chip, n, d, s):
                                    segmented=s > 1, squeeze_d=False).compile()
     assert compiled.as_text().count('"tpu_custom_call"') == 1
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5 * 2 ** 30
+
+
+def test_dense_decode_writes_its_caches_in_place(one_chip):
+    """The serving decode of a dense configuration (the stablelm-1.6b
+    smoke preset at four layers, so that its layers run in a scan)
+    compiled for a v5e: its output caches are its input caches (donated,
+    aliased), and no select or copy spans the stacked cache, so the step
+    writes its new rows where they lie instead of rewriting the cache."""
+    from repro.configs import get_smoke_config
+    from repro.models import init_params
+    from repro.serve.engine import Engine
+
+    cfg = get_smoke_config("stablelm-1.6b").scaled(n_layers=4)
+    b, max_len = 4, 256
+    eng = Engine(cfg, None, max_len=max_len, max_batch=b)
+
+    def shaped(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = shaped(jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    caches = shaped(eng._caches)
+    i32 = jax.ShapeDtypeStruct((b,), jnp.int32, sharding=one_chip)
+    compiled = eng._decode.lower(
+        params, jax.ShapeDtypeStruct((b, 1), jnp.int32, sharding=one_chip),
+        caches, i32,
+        jax.ShapeDtypeStruct((b,), jnp.bool_, sharding=one_chip)).compile()
+    kv = jax.tree.leaves(caches)[:2]
+    assert compiled.memory_analysis().alias_size_in_bytes >= \
+        sum(a.size * a.dtype.itemsize for a in kv)
+    text = compiled.as_text()
+    full = re.escape(f"{kv[0].dtype.name.replace('float', 'f')}"
+                     f"[{','.join(map(str, kv[0].shape))}]")
+    assert not re.search(rf"= {full}\S* (select|copy)\(", text)

@@ -268,12 +268,13 @@ def test_dense_configs_return_nothing_extra():
 
 
 def test_share_programs_update_caches_in_place():
-    """A share configuration's decode and prefill-chunk programs take the
-    caches donated (one copy of them in a step, not two); a dense
-    configuration's programs leave their input caches alive."""
+    """Every configuration's decode takes the caches donated (one copy of
+    them in a step, not two); a share configuration's prefill chunk does
+    too, and a dense configuration's chunk leaves its input caches
+    alive."""
     i32 = jnp.int32
-    for cfg, donated in ((SMOKE, True),
-                         (get_smoke_config("stablelm-1.6b"), False)):
+    for cfg, chunk_donates in ((SMOKE, True),
+                               (get_smoke_config("stablelm-1.6b"), False)):
         eng = Engine(cfg, init_params(jax.random.PRNGKey(0), cfg),
                      max_len=32, max_batch=2, prefill_chunk=4)
         old = eng._caches
@@ -282,7 +283,7 @@ def test_share_programs_update_caches_in_place():
         _, newer, _ = eng._decode(eng.params, jnp.ones((2, 1), i32), new,
                                   jnp.array([4, 0], i32),
                                   jnp.array([True, False]))
-        for caches in (old, new):
-            assert all(leaf.is_deleted() == donated
-                       for leaf in jax.tree.leaves(caches))
+        assert all(leaf.is_deleted() == chunk_donates
+                   for leaf in jax.tree.leaves(old))
+        assert all(leaf.is_deleted() for leaf in jax.tree.leaves(new))
         assert not any(leaf.is_deleted() for leaf in jax.tree.leaves(newer))
