@@ -12,21 +12,18 @@ Production (TPU-native) layer:
   trees        fixed pairing-tree reduction schedules
   segmented    segmented-reduction math oracle + flash-partial combines
                (the blocked schedule itself lives in repro.reduce.backends)
-  intac        exact integer-domain accumulation — limbs, exponent bins —
-               + deterministic / compressed collectives (surfaced as
-               reduce policies)
+  intac        the integer number formats — scale grid, limbs, exponent
+               bins — and the compressed collective; each integer tier's
+               one implementation is its repro.reduce Policy
   juggler      bounded-slot streaming gradient accumulation (surfaced as
                repro.reduce.TreeAccumulator)
 """
 
 from . import circuit, circuit_jax, intac, juggler, segmented, trees  # noqa: F401
 from .circuit import INTAC, JugglePAC, jugglepac_min_set_size  # noqa: F401
-from .intac import (bin_psum, compressed_psum_mean,  # noqa: F401
-                    compressed_psum_mean_tree, intac_psum, intac_psum2,
-                    intac_psum3, intac_sum, limb3_finalize, limb3_init,
-                    limb3_merge_across, limb_add, limb_add3, limb_finalize,
-                    limb_init, limb_merge, limb_merge3, limb_split3,
-                    limbs_canonical, limbs_resolve3)
+from .intac import (compressed_psum_mean, intac_sum,  # noqa: F401
+                    limb_add, limb_finalize, limb_init, limb_merge,
+                    limbs_canonical)
 from .juggler import (juggler_finalize, juggler_init,  # noqa: F401
                       juggler_push, num_slots_for)
 from .segmented import (combine_flash_partials_tree, flash_partial_combine,  # noqa: F401

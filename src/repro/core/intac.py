@@ -12,35 +12,32 @@ once per set* — maps onto TPU as fixed-point accumulation:
 
 Because integer addition is associative, the accumulation result is
 **bitwise independent of reduction order** — blocks, devices, pods — which is
-the TPU answer to the paper's FP non-associativity problem, and the basis of:
+the TPU answer to the paper's FP non-associativity problem.  This module
+holds the number formats; each integer tier's one implementation is its
+``Policy`` in ``repro.reduce.policy``, which every face (``reduce``, the
+``shard_map`` backend, ``collective_mean``, ``elastic_reduce_mean``) runs:
 
-  * ``intac_sum``           — exact, deterministic sum of an fp32 array;
-  * ``LimbAccumulator``     — two-limb int32 carry-save accumulator (wider
+  * ``choose_scale`` / ``quantize`` / ``descale`` — the shared power-of-two
+                              grid (the ``exact`` tier);
+  * ``LimbState`` et al.    — two-limb int32 carry-save state (wider
                               dynamic range, deferred carries; the closest
-                              software analogue of (sum, carry) feedback);
-  * ``limb_split3`` et al.  — the three-limb path: the exactly-captured
-                              quantization residual rides along as a
-                              compensated f32 limb, so "exact" holds for
-                              arbitrary f32 inputs, not just values on the
-                              scale's dyadic grid;
+                              software analogue of (sum, carry) feedback),
+                              and ``intac_sum``, exact sum of an array;
   * ``bin_split/combine``   — exponent-indexed "procrastination" bins
                               (Liguori/Neal): per-element exact digit
-                              split, all rounding deferred to one combine;
-  * ``intac_psum``          — deterministic cross-device reduction (plus
-                              ``intac_psum2`` / ``intac_psum3`` /
-                              ``bin_psum``, the two-limb, residual-carrying
-                              three-limb, and per-bin variants whose
-                              resolution does not shrink with the device
-                              count);
-  * ``CompressedAllReduce`` — int8/int16-quantized gradient all-reduce with
-                              error feedback (the distributed-optimization
-                              use of the same primitive).
+                              split, all rounding deferred to one combine
+                              (the ``procrastinate`` tier, and ``exact2``'s
+                              residual digits);
+  * ``limbs_resolve3_binned`` — ``exact2``'s once-per-set final addition;
+  * ``compressed_psum_mean`` — int8/int16-quantized gradient all-reduce
+                              with error feedback (the distributed-
+                              optimization use of the same primitive).
 """
 
 from __future__ import annotations
 
 from functools import partial
-from typing import NamedTuple, Optional, Tuple
+from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -270,148 +267,6 @@ def limb_merge(a: LimbState, b: LimbState) -> LimbState:
 
 
 # ---------------------------------------------------------------------------
-# Three-limb carry-save: the residual limb
-# ---------------------------------------------------------------------------
-#
-# The two-limb path quantizes each value to the shared power-of-two grid
-# and *discards* what the rounding dropped — exact only for inputs already
-# on the grid.  The third limb keeps that drop: because the scale is a
-# power of two, ``r = x - descale(quantize(x, scale), scale)`` is computed
-# *exactly* in f32 (the classic Dekker-split argument: q/scale is x
-# rounded to a coarser grid, the difference is a short-mantissa number and
-# the subtraction is exact by Sterbenz), so (hi, lo, r) represents x with
-# no information loss at all.  The integer limbs keep their associative /
-# bitwise-order-independent contract; in the *streaming* accumulator the
-# residual limb accumulates compensated-style (a two_sum-carried f32
-# pair), which pins its error at the ~f64 level — tolerance, not bits,
-# under re-ordering.  The block-schedule tier (``exact2``) goes further:
-# per-element residuals split into integer digit bins
-# (``RES_BIN_BITS``/``RES_NUM_BINS``) that accumulate associatively, so
-# its finalize (``limbs_resolve3_binned``) is bitwise order/topology
-# independent outright.  Either finalize is one carry-resolve +
-# compensated combine, within 1 ulp of the f64 reference for arbitrary
-# f32 streams.
-
-
-class Limb3State(NamedTuple):
-    """Three-limb redundant accumulator: (hi, lo) int32 carry-save limbs
-    plus the compensated f32 residual pair (res, comp).
-
-    value represented = (hi * 2^15 + lo) / scale + res + comp.
-
-    ``ovf`` is the saturation guard rail: an int32 count of integer-limb
-    wraparound events (``wrap_add``).  Nonzero means some limb overflowed
-    and the canonical integer total is wrong — the state is *detectably*
-    saturated rather than silently corrupt.  ``None`` (the pre-guard-rail
-    default, kept for 5-field constructors) disables tracking.
-    """
-    hi: jnp.ndarray    # int32
-    lo: jnp.ndarray    # int32
-    res: jnp.ndarray   # f32: exactly-captured quantization residuals
-    comp: jnp.ndarray  # f32: two_sum compensation of the residual limb
-    scale: jnp.ndarray
-    ovf: Optional[jnp.ndarray] = None   # int32 wrap-event count, or None
-
-
-def limb3_init(shape, scale) -> Limb3State:
-    z = jnp.zeros(shape, jnp.int32)
-    r = jnp.zeros(shape, jnp.float32)
-    return Limb3State(z, z, r, r, jnp.asarray(scale, jnp.float32), z)
-
-
-def limb_split3(x: jnp.ndarray, scale) -> Tuple[jnp.ndarray, jnp.ndarray,
-                                                jnp.ndarray]:
-    """Split one f32 operand into (hi, lo, residual) — lossless.
-
-    hi/lo are the integer limbs of ``quantize(x, scale)`` (pure shift/
-    mask, see ``limb_split``); the residual is what quantization rounded
-    away, computed exactly: scale is a power of two, so ``x * scale`` is
-    exact, ``q / scale`` is x rounded to the grid, and the subtraction of
-    two so-close values is exact (Sterbenz / Dekker).
-    """
-    x = jnp.asarray(x, jnp.float32)
-    q = quantize(x, scale)
-    hi, lo = limb_split(q)
-    return hi, lo, x - dequantize(q, scale)
-
-
-def limb_add3(state: Limb3State, x: jnp.ndarray) -> Limb3State:
-    """Accumulate one fp32 operand losslessly (3:2 compressor + residual).
-
-    Integer limbs add associatively; the residual folds through ``two_sum``
-    so its rounding error is carried, not dropped.  Limb adds run through
-    ``wrap_add``: a wrap at the int32 edge increments ``ovf`` in the same
-    fused update, so saturation is detected exactly when the canonical
-    integer total would be wrong (and never before — a carry landing *at*
-    ``2^31 - 1`` is still correct and raises no flag).
-    """
-    hi, lo, r = limb_split3(x, state.scale)
-    nhi, w1 = wrap_add(state.hi, hi)
-    nlo, w2 = wrap_add(state.lo, lo)
-    s, e = two_sum(state.res, r)
-    ovf = state.ovf
-    if ovf is not None:
-        ovf = ovf + w1.astype(jnp.int32) + w2.astype(jnp.int32)
-    return Limb3State(nhi, nlo, s, state.comp + e, state.scale, ovf)
-
-
-def limb_merge3(a: Limb3State, b: Limb3State) -> Limb3State:
-    """Merge two three-limb accumulators: integer limbs add exactly (any
-    order, same bits); the residual pair merges through ``two_sum`` —
-    deterministic for a pinned merge order, ulp-level drift otherwise.
-    Wrap flags from the merge adds pool into ``ovf`` alongside both
-    sides' prior counts, so saturation anywhere in a merge tree survives
-    to ``finalize``."""
-    nhi, w1 = wrap_add(a.hi, b.hi)
-    nlo, w2 = wrap_add(a.lo, b.lo)
-    s, e = two_sum(a.res, b.res)
-    ovf = None
-    if a.ovf is not None or b.ovf is not None:
-        za = jnp.zeros_like(nhi)
-        ovf = ((a.ovf if a.ovf is not None else za)
-               + (b.ovf if b.ovf is not None else za)
-               + w1.astype(jnp.int32) + w2.astype(jnp.int32))
-    return Limb3State(nhi, nlo, s, a.comp + b.comp + e, a.scale, ovf)
-
-
-def limbs_resolve3(hi: jnp.ndarray, lo: jnp.ndarray, res: jnp.ndarray,
-                   scale, comp: Optional[jnp.ndarray] = None) -> jnp.ndarray:
-    """Carry-resolve the integer limbs, fold the residual limb back in,
-    descale — the three-limb once-per-set final addition.
-
-    The integer canonicalization (as in ``limbs_resolve``) makes the
-    (hi, lo) pair a pure function of the accumulated integer total, so
-    that part of the result is bitwise independent of blocking/ordering.
-    The integer total is then *exactly* decomposed into f32-representable
-    pieces (hi alone can exceed the 24-bit mantissa, so hi splits once
-    more) and combined with the residual pair least-significant-first
-    through compensated two_sums — the one rounding the caller sees is
-    the final one, keeping the result within 1 ulp of the f64 reference.
-    """
-    hi, lo = limbs_canonical(hi, lo)
-    # hi may need up to 31 bits: split into two exactly-convertible pieces
-    _HSPLIT = 14
-    hih = jnp.right_shift(hi, _HSPLIT)               # |hih| <= 2^17
-    hil = jnp.bitwise_and(hi, (1 << _HSPLIT) - 1)    # in [0, 2^14)
-    acc = res.astype(jnp.float32)
-    cmp_ = (jnp.zeros_like(acc) if comp is None
-            else comp.astype(jnp.float32))
-    # detlint: ok[DET002] two_sum resolve chain: order pinned by data
-    # dependence through acc; the final rounding is pinned by ulp tests
-    for quanta, shift in ((lo, 0), (hil, LIMB_SHIFT),
-                          (hih, LIMB_SHIFT + _HSPLIT)):
-        term = descale(_ldexp2(quanta.astype(jnp.float32), shift), scale)
-        acc, e = two_sum(acc, term)
-        cmp_ = cmp_ + e
-    return acc + cmp_
-
-
-def limb3_finalize(state: Limb3State) -> jnp.ndarray:
-    return limbs_resolve3(state.hi, state.lo, state.res, state.scale,
-                          comp=state.comp)
-
-
-# ---------------------------------------------------------------------------
 # Exponent-indexed bins ("procrastination" accumulation)
 # ---------------------------------------------------------------------------
 #
@@ -584,119 +439,6 @@ def limbs_resolve3_binned(hi: jnp.ndarray, lo: jnp.ndarray,
 # ---------------------------------------------------------------------------
 
 
-def intac_psum(x: jnp.ndarray, axis_name, *, qbits: int = 30,
-               nterms: Optional[int] = None) -> jnp.ndarray:
-    """Bitwise-deterministic cross-device sum (shard_map collective).
-
-    All devices agree on a power-of-two scale (via a max-reduce), quantize,
-    integer-psum (associative => any reduction topology gives the same bits),
-    dequantize once.  Works across 'data', ('data','pod'), etc.
-    """
-    n = nterms or jax.lax.psum(1, axis_name)
-    gmax = jax.lax.pmax(jnp.max(jnp.abs(x)), axis_name)
-    scale = choose_scale(gmax, n, qbits)
-    q = quantize(x, scale)
-    return dequantize(jax.lax.psum(q, axis_name), scale)
-
-
-def intac_psum2(x: jnp.ndarray, axis_name, *, qbits: int = 30) -> jnp.ndarray:
-    """Two-limb exact cross-device sum: full f32-headroom resolution.
-
-    Unlike ``intac_psum`` — whose shared scale shrinks with the device
-    count to keep the single int32 sum in headroom — the scale here is
-    sized by magnitude alone (``num_terms=1``): each device splits its
-    full-width int32 quantization into (hi, lo) limbs, both limbs psum in
-    the exact integer domain (per-device |hi| <= 2^(qbits-15) and lo <
-    2^15, so up to 2^15 devices carry-free at qbits=30), and one
-    ``limbs_resolve`` per reduction pays for the normalization.
-    """
-    gmax = jax.lax.pmax(jnp.max(jnp.abs(x)), axis_name)
-    scale = choose_scale(gmax, 1, qbits)
-    hi, lo = limb_split(quantize(x, scale))
-    return limbs_resolve(jax.lax.psum(hi, axis_name),
-                         jax.lax.psum(lo, axis_name), scale)
-
-
-def limb3_merge_across(hi: jnp.ndarray, lo: jnp.ndarray, res: jnp.ndarray,
-                       comp: jnp.ndarray, axis_names) -> Tuple[
-                           jnp.ndarray, jnp.ndarray, jnp.ndarray,
-                           jnp.ndarray]:
-    """The one cross-device merge of three-limb state (inside shard_map).
-
-    Integer limbs reduce with one associative int32 ``psum`` each — any
-    reduction topology, same bits, at any device count.  The residual
-    pair reduces through a small superaccumulator (Neal, arXiv
-    1505.05571): every device splits res and comp into exponent-indexed
-    integer digits of a window anchored at the global (pmax-shared)
-    residual maximum, the digit bins ``psum`` in the exact integer
-    domain, and one carry-resolve + compensated combine rebuilds a float
-    residual.  Both the anchor and the integer bin sums are pure
-    functions of the *global* per-device residuals — no device-order
-    fold remains, so the merged state (and everything finalized from it)
-    is bitwise identical at any device count, mesh shape, or device
-    permutation.  Every layer that merges three-limb state across
-    devices (the exact2 policy, ``Limb3Accumulator``, ``intac_psum3``)
-    delegates here so the semantics cannot drift apart.
-    """
-    axes = tuple(axis_names)
-    m = jnp.maximum(jnp.max(jnp.abs(res)), jnp.max(jnp.abs(comp)))
-    e_ref = bin_ref_exponent(jax.lax.pmax(m, axes))
-    digits = (bin_split(res, e_ref, bits=RES_BIN_BITS, num=RES_NUM_BINS)
-              + bin_split(comp, e_ref, bits=RES_BIN_BITS,
-                          num=RES_NUM_BINS))
-    # one fused int32 psum for all three integer components: psum is
-    # elementwise, so summing [hi | lo | digits] concatenated is the same
-    # bits as three separate collectives — at a third of the latency
-    # floor.  Only the anchor pmax remains separate (it gates digits).
-    flat = jax.lax.psum(
-        jnp.concatenate([hi.ravel(), lo.ravel(), digits.ravel()]), axes)
-    hi = flat[:hi.size].reshape(hi.shape)
-    lo = flat[hi.size:hi.size + lo.size].reshape(lo.shape)
-    digits = flat[hi.size + lo.size:].reshape(digits.shape)
-    res = bin_combine(digits, e_ref, bits=RES_BIN_BITS)
-    return hi, lo, res, jnp.zeros_like(res)
-
-
-def intac_psum3(x: jnp.ndarray, axis_name, *, qbits: int = 30) -> jnp.ndarray:
-    """Three-limb exact cross-device sum: two-limb resolution *plus* the
-    exactly-captured quantization residual.
-
-    The integer limbs follow ``intac_psum2`` bit for bit (one associative
-    int32 psum per limb — any reduction topology, same bits); the residual
-    limb reduces through the binned superaccumulator of
-    ``limb3_merge_across`` — per-element digit splits into integer bins
-    that psum associatively, anchored at a pmax-shared window.  Because
-    the per-element digits depend only on each element's value and the
-    global anchor, the finalized sum is **bitwise identical at any device
-    count or mesh shape**, and within 1 ulp of the f64 reference for
-    arbitrary f32 inputs — the residual makes "exact" hold off the
-    dyadic grid too.
-    """
-    gmax = jax.lax.pmax(jnp.max(jnp.abs(x)), axis_name)
-    scale = choose_scale(gmax, 1, qbits)
-    hi, lo, res = limb_split3(x, scale)
-    hi, lo, res, comp = limb3_merge_across(hi, lo, res, jnp.zeros_like(res),
-                                           axis_name)
-    return limbs_resolve3(hi, lo, res, scale, comp=comp)
-
-
-def bin_psum(x: jnp.ndarray, axis_name) -> jnp.ndarray:
-    """Exponent-binned exact cross-device sum (per-bin integer psum).
-
-    All devices agree on the window anchor via a pmax, split locally into
-    exponent-bin digits, psum the int32 bins (associative => bitwise
-    identical for any reduction topology), and carry-resolve once.
-    """
-    gmax = jax.lax.pmax(jnp.max(jnp.abs(x)), axis_name)
-    e_ref = bin_ref_exponent(gmax)
-    return bin_combine(jax.lax.psum(bin_split(x, e_ref), axis_name), e_ref)
-
-
-class EFState(NamedTuple):
-    """Error-feedback residual for compressed gradient all-reduce."""
-    residual: jnp.ndarray
-
-
 def compressed_psum_mean(x: jnp.ndarray, residual: jnp.ndarray, axis_name,
                          *, bits: int = 8) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """INTAC-style compressed gradient all-reduce with error feedback.
@@ -722,19 +464,3 @@ def compressed_psum_mean(x: jnp.ndarray, residual: jnp.ndarray, axis_name,
     total = jax.lax.psum(q, axis_name)          # int32 accumulate (exact)
     mean = dequantize(total, scale) / n
     return mean, new_residual
-
-
-def compressed_psum_mean_tree(grads, residuals, axis_name, *, bits: int = 8):
-    """Pytree version of ``compressed_psum_mean``."""
-    flat_g, tdef = jax.tree.flatten(grads)
-    flat_r = tdef.flatten_up_to(residuals)
-    out, res = [], []
-    for g, r in zip(flat_g, flat_r):
-        m, nr = compressed_psum_mean(g, r, axis_name, bits=bits)
-        out.append(m)
-        res.append(nr)
-    return tdef.unflatten(out), tdef.unflatten(res)
-
-
-def zeros_like_residuals(grads):
-    return jax.tree.map(jnp.zeros_like, grads)

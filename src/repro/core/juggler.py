@@ -19,8 +19,8 @@ n for a naive tree, 1 for serial).
 Why bother vs serial ``+=``: the pairing tree's rounding-error growth is
 O(log n) instead of O(n) — the paper's numerical motivation — and the fixed
 schedule makes gradient accumulation bitwise independent of how microbatches
-are grouped, which combines with ``intac_psum`` to give fully deterministic
-distributed training.
+are grouped, which combines with an integer-tier ``collective_mean`` to
+give fully deterministic distributed training.
 """
 
 from __future__ import annotations

@@ -8,10 +8,9 @@ stream through the Accumulator protocol (or, with ``microbatch_reduce``,
 through a ``repro.reduce`` segment reduction under any accuracy policy),
 and the cross-device mean is a ``repro.reduce.collective_mean`` policy —
 ``fast`` (plain hierarchical), ``compensated`` (INTAC compressed + error
-feedback), ``exact`` (full-width integer psum), ``exact2`` (three-limb
-psum: integer limbs + the exactly-captured quantization residual), or
-``procrastinate`` (per-bin psum).  The JugglePAC/INTAC distributed
-tricks:
+feedback), or an integer tier, ``exact``, ``exact2`` or
+``procrastinate``, which runs its ``Policy`` as ``repro.reduce`` does.
+The JugglePAC/INTAC distributed tricks:
 
   1. **INTAC compressed all-reduce** — gradients are quantized to ``bits``-bit
      fixed point with a shared power-of-two scale, summed in the exact
@@ -30,12 +29,12 @@ tricks:
   4. **Fused merge collectives** — every cross-device merge on this path
      is batched per dtype rather than issued per component: the fast-tier
      gradient tree fuses all leaves into one psum per mesh axis
-     (``collective_mean_tree``), the exact2 three-limb merge ships
-     [hi | lo | residual-digits] as a single int32 psum
-     (``core.intac.limb3_merge_across``), and policy-carry merges go
-     through ``reduce.policy.fused_psum``.  psum is elementwise, so the
-     fusion is bitwise invisible — it only removes per-collective latency
-     floors, which dominate once the per-shard kernel tail shrinks.
+     (``collective_mean_tree``), and the integer tiers' carries —
+     exact2's [hi | lo | residual digits | overflow count] — merge as
+     one int32 psum through ``reduce.policy.fused_psum``.  psum is
+     elementwise, so the fusion is bitwise invisible — it only removes
+     per-collective latency floors, which dominate once the per-shard
+     kernel tail shrinks.
 
 ``make_elastic_train_step`` is the topology-elastic variant: gradients
 and loss cross the device boundary only through

@@ -37,10 +37,11 @@ Entry points:
       the call — see ``api.py``; ``ReduceSpec`` for reusable static specs.
   ``Accumulator`` protocol (``accumulator.py``)
       streaming init/push/merge/finalize — TreeAccumulator (gradient
-      juggler), KahanAccumulator, LimbAccumulator (INTAC), and
-      FlashAccumulator (online softmax) compose with lax.scan and trees.
+      juggler), FlashAccumulator (online softmax) and CascadeAccumulator
+      compose with lax.scan and trees.
   ``collective_mean`` (``collective.py``)
-      the same policy knob for cross-device gradient means;
+      the same policy knob for cross-device gradient means (the integer
+      tiers run their ``Policy``, as ``reduce`` does);
       ``elastic_reduce_mean`` for the topology-elastic (resume-anywhere)
       global mean.
   ``ReduceStatus`` (``api.py``)
@@ -53,10 +54,8 @@ Entry points:
       sum and count, on every backend.
 """
 
-from .accumulator import (Accumulator, BinAccumulator,  # noqa: F401
-                          CascadeAccumulator, FlashAccumulator,
-                          KahanAccumulator, Limb3Accumulator,
-                          LimbAccumulator, TreeAccumulator,
+from .accumulator import (Accumulator, CascadeAccumulator,  # noqa: F401
+                          FlashAccumulator, TreeAccumulator,
                           accumulate_microbatch_grads, merge_across,
                           merge_tree, reduce_microbatch_grads,
                           scan_accumulate)
@@ -100,9 +99,8 @@ __all__ = [
     "Backend", "BACKENDS", "register_backend", "get_backend",
     "select_backend", "select_local_backend", "mask_out_of_range",
     "ambient_mesh", "default_mesh",
-    "Accumulator", "TreeAccumulator", "KahanAccumulator",
-    "LimbAccumulator", "Limb3Accumulator", "BinAccumulator",
-    "FlashAccumulator", "CascadeAccumulator",
+    "Accumulator", "TreeAccumulator", "FlashAccumulator",
+    "CascadeAccumulator",
     "scan_accumulate", "merge_tree", "merge_across",
     "accumulate_microbatch_grads", "reduce_microbatch_grads",
     "collective_mean", "collective_mean_tree", "COLLECTIVE_POLICIES",

@@ -1,10 +1,10 @@
-"""The streaming ``Accumulator`` protocol — one contract for every state
-machine in the repo.
+"""The streaming ``Accumulator`` protocol — one contract for the streaming
+state machines in the repo.
 
 JugglePAC is ultimately a streaming accumulator with bounded state; the
-repo grew three ad-hoc incarnations of that idea (the gradient juggler's
-``JugglerState``, INTAC's ``LimbState``, flash-decode's (m, l, o)
-partials), each with its own init/step/merge spelling.  This module gives
+repo grew ad-hoc incarnations of that idea (the gradient juggler's
+``JugglerState``, flash-decode's (m, l, o) partials, the cascaded
+stages), each with its own init/step/merge spelling.  This module gives
 them one protocol:
 
     init(template)      -> state        bounded, pytree-shaped
@@ -15,27 +15,18 @@ them one protocol:
 
 Any instance composes with ``lax.scan`` (push is the step function) and
 with fixed pairing trees (``merge_tree``), so the same code path handles
-microbatch gradients, exact distributed sums, and attention partials.
+microbatch gradients and attention partials.  The accuracy tiers are not
+accumulators: each is one ``Policy`` (``policy.py``), which ``reduce``,
+the ``shard_map`` backend and the collectives all run.
 
 Instances:
   * ``TreeAccumulator``  — binary-counter pairwise tree (wraps
     ``core.juggler``): O(log n) live state, O(log n) error growth.
-  * ``KahanAccumulator`` — (sum, compensation) two-sum pair: O(1) state,
-    ~f64 accuracy.
-  * ``LimbAccumulator``  — INTAC two-limb int32 carry-save (wraps
-    ``core.intac``): exact, order-independent, one rounding at finalize.
-  * ``Limb3Accumulator`` — the three-limb variant: the exactly-captured
-    quantization residual rides along as a compensated f32 limb, so the
-    finalized sum is within 1 ulp of the f64 reference for arbitrary f32
-    streams — not just values on the scale's dyadic grid.  Integer limbs
-    keep the bitwise order-independent contract; the residual pair is
-    order-pinned tolerance.
-  * ``BinAccumulator``   — exponent-indexed "procrastination" bins (wraps
-    ``core.intac`` bin_split/combine): exact for any f32 within the
-    window, order-independent, all rounding deferred to finalize.
   * ``FlashAccumulator`` — online-softmax (m, l, o) triple (wraps
     ``core.segmented``): the "any multi-cycle operator" clause of the
     paper, instantiated for attention.
+  * ``CascadeAccumulator`` — ``depth`` chained plain accumulators, the
+    cascaded-PAC time-index weighting.
 """
 
 from __future__ import annotations
@@ -46,8 +37,7 @@ from typing import Any, Protocol, runtime_checkable
 import jax
 import jax.numpy as jnp
 
-from repro.core import intac, juggler
-from .policy import fused_psum, two_sum
+from repro.core import juggler
 
 
 @runtime_checkable
@@ -60,7 +50,7 @@ class Accumulator(Protocol):
     distributed face.
 
     >>> import jax.numpy as jnp
-    >>> acc = KahanAccumulator()
+    >>> acc = TreeAccumulator(num_slots=2)
     >>> st = acc.init(jnp.zeros(2))
     >>> st = acc.push(st, jnp.asarray([1.0, 2.0]))
     >>> st = acc.push(st, jnp.asarray([3.0, 4.0]))
@@ -108,147 +98,6 @@ class TreeAccumulator:
 
     def finalize(self, state, *, mean: bool = False):
         return juggler.juggler_finalize(state, mean=mean)
-
-
-class KahanAccumulator:
-    """Compensated (sum, comp) accumulation of a single array/pytree."""
-
-    def init(self, template):
-        z = jax.tree.map(lambda t: jnp.zeros(jnp.shape(t), jnp.float32),
-                         template)
-        return (z, jax.tree.map(jnp.zeros_like, z))
-
-    def push(self, state, x):
-        acc, comp = state
-        # two maps so tuple-valued two_sum never confuses pytree flattening
-        # (XLA CSE merges the duplicated arithmetic under jit).
-        s = jax.tree.map(lambda a, b: two_sum(a, b)[0], acc, x)
-        e = jax.tree.map(lambda a, b: two_sum(a, b)[1], acc, x)
-        return (s, jax.tree.map(jnp.add, comp, e))
-
-    def merge(self, a, b):
-        state = self.push(a, b[0])                   # two-sum the sums
-        return (state[0],
-                jax.tree.map(lambda c, cb: c + cb, state[1], b[1]))
-
-    def finalize(self, state):
-        acc, comp = state
-        return jax.tree.map(lambda a, c: a + c, acc, comp)
-
-
-class LimbAccumulator:
-    """INTAC two-limb carry-save accumulation (exact within quantization).
-
-    ``scale`` is the shared power-of-two from ``intac.choose_scale`` — the
-    a-priori bit-width parameterization; push/merge are pure integer ops.
-
-    >>> import jax.numpy as jnp
-    >>> acc = LimbAccumulator(2.0 ** 16)
-    >>> a, b = acc.init(jnp.zeros(1)), acc.init(jnp.zeros(1))
-    >>> for _ in range(10):
-    ...     a = acc.push(a, jnp.asarray([0.5]))
-    ...     b = acc.push(b, jnp.asarray([0.25]))
-    >>> float(acc.finalize(acc.merge(a, b))[0])     # exact, order-free
-    7.5
-    """
-
-    def __init__(self, scale):
-        self.scale = scale
-
-    def init(self, template) -> intac.LimbState:
-        return intac.limb_init(jnp.shape(template), self.scale)
-
-    def push(self, state, x) -> intac.LimbState:
-        return intac.limb_add(state, x)
-
-    def merge(self, a, b) -> intac.LimbState:
-        return intac.limb_merge(a, b)
-
-    def finalize(self, state) -> jnp.ndarray:
-        return intac.limb_finalize(state)
-
-
-class Limb3Accumulator:
-    """INTAC three-limb carry-save accumulation: exact for arbitrary f32.
-
-    ``LimbAccumulator`` with the dyadic-grid caveat removed: pushes split
-    each operand losslessly into (hi, lo, residual) — the residual is
-    what quantization rounded away, captured exactly and folded through a
-    compensated ``two_sum`` pair.  The integer limbs keep the bitwise
-    order-independent contract; ``finalize`` is one carry-resolve +
-    compensated combine within 1 ulp of the f64 reference.
-
-    >>> import jax.numpy as jnp
-    >>> acc = Limb3Accumulator(2.0 ** 16)
-    >>> st = acc.init(jnp.zeros(1))
-    >>> for _ in range(3):
-    ...     st = acc.push(st, jnp.asarray([1 / 3]))    # off the grid
-    >>> float(abs(acc.finalize(st)[0] - 1.0)) < 1e-7
-    True
-    """
-
-    def __init__(self, scale):
-        self.scale = scale
-
-    def init(self, template) -> intac.Limb3State:
-        return intac.limb3_init(jnp.shape(template), self.scale)
-
-    def push(self, state, x) -> intac.Limb3State:
-        return intac.limb_add3(state, x)
-
-    def merge(self, a, b) -> intac.Limb3State:
-        return intac.limb_merge3(a, b)
-
-    def merge_across(self, state, axis_names):
-        """Cross-device merge (inside shard_map), taken by the module
-        ``merge_across`` in place of its generic paths: the one shared
-        three-limb lowering (``core.intac.limb3_merge_across`` — the
-        residual pair re-binned as exponent-indexed digits, then one
-        *fused* int32 psum over [hi | lo | digits]); the shared scale
-        leaf passes through untouched, and the wrap-event count
-        (overflow guard rail) psums like any other integer component."""
-        hi, lo, res, comp = intac.limb3_merge_across(
-            state.hi, state.lo, state.res, state.comp, axis_names)
-        ovf = (None if state.ovf is None
-               else jax.lax.psum(state.ovf, tuple(axis_names)))
-        return intac.Limb3State(hi, lo, res, comp, state.scale, ovf)
-
-    def finalize(self, state) -> jnp.ndarray:
-        return intac.limb3_finalize(state)
-
-
-class BinAccumulator:
-    """Exponent-indexed bin accumulation (Liguori's procrastination /
-    Neal's small superaccumulator, int32 edition).
-
-    ``max_abs`` anchors the fixed-point window a priori — the bin
-    analogue of ``LimbAccumulator``'s shared scale; pushes are exact
-    digit splits + integer adds (order-independent), and the one rounding
-    happens in ``finalize``.  Up to ``intac.BIN_MAX_TERMS`` (= 2^22)
-    pushes accumulate with no bin overflow.
-    """
-
-    #: every state leaf merges by addition, so a cross-device merge may
-    #: lower to one fused associative psum per dtype (see
-    #: ``merge_across``).
-    #: LimbAccumulator cannot claim this: its state carries the shared
-    #: ``scale`` leaf, which ``merge`` keeps rather than adds.
-    merge_is_add = True
-
-    def __init__(self, max_abs):
-        self.e_ref = intac.bin_ref_exponent(max_abs)
-
-    def init(self, template):
-        return jnp.zeros((intac.NUM_BINS,) + jnp.shape(template), jnp.int32)
-
-    def push(self, state, x):
-        return state + intac.bin_split(x, self.e_ref)
-
-    def merge(self, a, b):
-        return a + b
-
-    def finalize(self, state) -> jnp.ndarray:
-        return intac.bin_combine(state, self.e_ref)
 
 
 class FlashAccumulator:
@@ -391,49 +240,33 @@ def merge_across(acc: Accumulator, state, axis_names):
 
     Every ``Accumulator`` states its combiner as ``merge``; this is the
     collective face of that contract — the same role
-    ``collective.merge_carry_across`` plays for policy carries.  An
-    accumulator with its own ``merge_across`` method (Limb3Accumulator:
-    psum'd integer limbs + an order-pinned residual fold) keeps full
-    control of the lowering; one declaring ``merge_is_add`` (every state
-    leaf merges by plain addition, e.g. BinAccumulator) reduces with one
-    *fused* batched ``psum`` per dtype — the leaves ravel-concat into a
-    single collective (``policy.fused_psum``), bitwise identical to
-    per-leaf psums because psum is elementwise; otherwise each leaf
-    all-gathers along
-    ``axis_names`` and the per-device states fold strictly in device
-    order, so the combine schedule is a pure function of the mesh —
-    deterministic, and exact whenever ``merge`` is (LimbAccumulator,
-    BinAccumulator).
+    ``collective.merge_carry_across`` plays for policy carries.  Each
+    state leaf all-gathers along ``axis_names`` and the per-device
+    states fold strictly in device order, so the combine schedule is a
+    pure function of the mesh — deterministic, and exact whenever
+    ``merge`` is.
 
     Example (one-device mesh; any device count works the same way):
 
     >>> import jax, jax.numpy as jnp, numpy as np
     >>> from jax.sharding import Mesh, PartitionSpec as P
     >>> mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
-    >>> acc = KahanAccumulator()
+    >>> acc = CascadeAccumulator(1)
     >>> def f(x):
     ...     st = acc.push(acc.init(x), x)          # local partial stream
-    ...     return acc.finalize(merge_across(acc, st, ("data",)))
+    ...     return acc.finalize(merge_across(acc, st, ("data",)))[0]
     >>> out = jax.shard_map(f, mesh=mesh, in_specs=P(), out_specs=P(),
     ...                     check_vma=False)(jnp.asarray([2.0, 3.0]))
     >>> [float(v) for v in out]
     [2.0, 3.0]
     """
     axes = tuple(axis_names)
-    own = getattr(acc, "merge_across", None)
-    if callable(own):
-        return own(state, axes)
-    if getattr(acc, "merge_is_add", False):
-        # one batched collective per dtype instead of one psum per leaf:
-        # psum is elementwise, so the fused form is bitwise identical
-        leaves, treedef = jax.tree.flatten(state)
-        return jax.tree.unflatten(treedef, fused_psum(leaves, axes))
     gathered = jax.tree.map(
         lambda x: jax.lax.all_gather(x, axes, axis=0), state)
     nshards = jax.tree.leaves(gathered)[0].shape[0]
     merged = jax.tree.map(lambda x: x[0], gathered)
     # detlint: ok[DET002] strict device-order merge is the contract:
-    # merge chains are two_sum data-dependent or integer-exact
+    # the fold order is a pure function of the mesh
     for k in range(1, nshards):
         merged = acc.merge(merged, jax.tree.map(lambda x: x[k], gathered))
     return merged

@@ -1,9 +1,7 @@
 """Policy-selectable cross-device means — the distributed face of
 ``repro.reduce``.
 
-The repo's three gradient all-reduce flavors were separate functions
-(``_hierarchical_mean``, ``compressed_psum_mean``, ``intac_psum``); here
-they are the same accuracy knob the array API exposes:
+Gradient all-reduces take the same accuracy knob the array API exposes:
 
   * ``fast``        — hierarchical fp32 psum ('data' in-pod ICI first,
                       then 'pod' DCI), divide once.
@@ -12,23 +10,19 @@ they are the same accuracy knob the array API exposes:
     psum in the exact integer domain, dequantize once; the local
     quantization error is carried as next step's residual — the
     collective analogue of a Kahan compensation term (bits/32 of the
-    fp32 payload on the wire).
-  * ``exact``       — full-width INTAC integer psum: bitwise-deterministic
-    for any reduction topology / pod layout, no compression.  The shared
-    scale shrinks with the device count (single-limb headroom).
-  * ``exact2``      — three-limb INTAC psum: the per-device hi/lo limb
-    split keeps full-resolution quantization (scale sized by magnitude
-    alone) for up to 2^15 devices, and the exactly-captured quantization
-    residual is re-expressed as exponent-indexed int32 digits (a small
-    Neal-style superaccumulator, arXiv 1505.05571) that psum exactly, so
-    the mean is within 1 ulp of the f64 reference *and* bitwise-invariant
-    across device count, mesh shape, and device permutation; one
-    carry-resolve per reduction.
-  * ``procrastinate`` — per-exponent-bin integer psum: each device splits
-    its gradient into exponent-window digits, every bin psums in the
-    exact integer domain, and one carry-resolve + compensated combine
-    defers all rounding — <=1 ulp of the f32 mean for any topology
-    (absolute 2^-49-of-max bound when devices cancel catastrophically).
+    fp32 payload on the wire).  A different algorithm from ``reduce``'s
+    two-sum ``compensated`` tier, under the same name.
+  * ``exact`` / ``exact2`` / ``procrastinate`` — the integer tiers are
+    their ``Policy`` (``policy.py``), run by the recipe
+    ``elastic_reduce_mean`` runs (``_policy_mean``), one term per
+    device: a ``pmax``-shared max and the device count size the grid
+    (``Policy.prepare_ctx``), ``Policy.to_domain`` maps the leaf into
+    the integer domain a chunk of rows at a time, the carries merge with
+    one associative int32 ``psum`` (``merge_carry_across``), and
+    ``Policy.finalize`` resolves each chunk once.
+    That is the path the ``shard_map`` backend of ``reduce`` runs, so a
+    collective mean is bitwise the ``op="mean"`` reduction of the same
+    rows, at any device count, mesh shape or device permutation.
 
 All tiers share one signature so training code switches policy without
 rewiring residual plumbing: ``(mean, new_residual)`` — every tier except
@@ -41,9 +35,8 @@ Must be called inside ``shard_map`` (they use named-axis collectives).
 ``collective_mean`` reduces *raw gradients* across devices, it reduces
 *policy carries* — the partial block-schedule state each shard of the
 ``shard_map`` backend produced — with the policy's own combiner (one
-integer ``psum`` per integer carry component, a gathered in-order
-two-sum fold for order-sensitive float state: compensated's carry,
-exact2's residual limb).
+fused integer ``psum`` for the integer tiers, a gathered in-order
+two-sum fold for compensated's float carry).
 """
 
 from __future__ import annotations
@@ -59,6 +52,11 @@ from .policy import Policy, fused_psum, get_policy
 
 COLLECTIVE_POLICIES = ("fast", "compensated", "exact", "exact2",
                        "procrastinate")
+#: the integer tiers' layout of a leaf: rows of this many lanes, reduced
+#: in chunks of at most this many rows (2^21 values: an exact2 chunk's
+#: temporaries are about 0.2 GiB, whatever the leaf's size)
+_ROW_LANES = 1024
+_CHUNK_ROWS = 2048
 
 
 def merge_carry_across(policy: Policy, carry, axis_names):
@@ -67,10 +65,9 @@ def merge_carry_across(policy: Policy, carry, axis_names):
     ``carry`` is the policy carry tuple a local backend produced from a
     shard's blocks.  The lowering is the policy's own
     (``Policy.merge_across``): one associative int32 psum per integer
-    carry component (any psum topology gives the same bits — the
-    ``intac_psum3``/``bin_psum`` argument applied to carries that are
-    *already* in the integer domain; since the residual-digit redesign
-    this covers every exact2 component too), and an all-gather + strict
+    carry component (any psum topology gives the same bits; every
+    component of exact, exact2 and procrastinate is an int32 that merges
+    by addition), and an all-gather + strict
     device-order fold with ``policy.merge`` for order-sensitive float
     state (compensated's carry), which pins the combine schedule the way
     the block schedule pins per-shard order.
@@ -106,21 +103,28 @@ def collective_mean(x: jnp.ndarray, axis_names: Sequence[str], *,
             g = jax.lax.psum(g, a)      # innermost (fastest) axis first
         return g / jax.lax.psum(jnp.float32(1.0), axes), residual  # detlint: ok[DET006] device count well under 2^24; one collective keeps the fast tier fast
 
-    # the integer tiers are the core INTAC collectives (one copy of each
-    # quantize/psum/resolve recipe lives in core/intac.py); integer sums
-    # are associative, so the joint-axes psum is bitwise identical to any
-    # hierarchical per-axis order.
-    if policy == "exact":
-        n = jax.lax.psum(1, axes)
-        return intac.intac_psum(x, axes) / n, residual
+    # the integer tiers are their policies, one term per device.  The
+    # leaf is laid out as lane-dense rows, each row its own segment (a
+    # device's contribution to a segment is its mapped row, so one
+    # ``update`` gives the carry a one-row block would), in chunks of
+    # rows reduced one after another, so the domain's temporaries are a
+    # chunk's and not the leaf's
+    if policy in ("exact", "exact2", "procrastinate"):
+        pol = get_policy(policy)
+        n = x.size
+        w = min(_ROW_LANES, max(n, 1))
+        rows = -(-n // w)
+        chunks = -(-rows // _CHUNK_ROWS)
+        step = -(-rows // chunks)
+        flat = jnp.pad(x.reshape(-1), (0, chunks * step * w - n))
 
-    if policy == "exact2":
-        n = jax.lax.psum(1, axes)
-        return intac.intac_psum3(x, axes) / n, residual
+        def carry(domain):
+            return pol.update(pol.init(step, domain.shape[1]),
+                              domain.astype(pol.acc_dtype))
 
-    if policy == "procrastinate":
-        n = jax.lax.psum(1, axes)
-        return intac.bin_psum(x, axes) / n, residual
+        out = _policy_mean(pol, flat.reshape(chunks, step, w), 1, axes,
+                           carry)
+        return out.reshape(-1)[:n].reshape(x.shape), residual
 
     if policy == "compensated":
         if residual is None:       # only this policy materializes a state
@@ -221,20 +225,42 @@ def elastic_reduce_mean(stack: jnp.ndarray, axis_names, *,
     >>> [float(v) for v in out]
     [1.0, 3.0]
     """
-    axes = tuple(axis_names)
     pol = get_policy(policy)
     m_local = stack.shape[0]
     flat = stack.reshape(m_local, -1)                       # (m, D)
-    num_total = jax.lax.psum(m_local, axes)
-    # shared grid: every shard quantizes against the global max
-    gmax = jax.lax.pmax(jnp.max(jnp.abs(flat)), axes)
-    domain, ctx = pol.prepare(flat, num_total, shared_max=gmax)
-    ids = jnp.zeros(m_local, jnp.int32)
-    carry = get_backend("blocked").run(domain, ids, 1, policy=pol,
-                                       block_size=block_size)
-    carry = merge_carry_across(pol, carry, axes)
-    out = pol.finalize(carry, ctx)[0]                       # (D,)
-    return (out / num_total).reshape(stack.shape[1:])
+
+    def carry(domain):
+        return get_backend("blocked").run(
+            domain, jnp.zeros(m_local, jnp.int32), 1, policy=pol,
+            block_size=block_size)
+
+    out = _policy_mean(pol, flat[None], m_local, tuple(axis_names), carry)
+    return out[0, 0].reshape(stack.shape[1:])
+
+
+def _policy_mean(pol: Policy, rows: jnp.ndarray, num_local: int, axes,
+                 local_carry) -> jnp.ndarray:
+    """The cross-device mean of one policy, shared by ``collective_mean``
+    and ``elastic_reduce_mean``: ``num_local`` terms on this shard,
+    ``rows`` their values as (C, R, D) chunks, and ``local_carry`` the
+    shard's (R-segment) carry of one chunk's mapped domain.  Every shard
+    maps on the grid of the ``pmax``-shared global max with the global
+    term count; chunk by chunk, the carries merge across ``axes`` and
+    the finalized sums divide by the count.  The map is row-local, so
+    the chunking moves no bit.
+    """
+    num_total = jax.lax.psum(num_local, axes)
+    gmax = jax.lax.pmax(jnp.max(jnp.abs(rows)), axes)
+    ctx = pol.prepare_ctx(gmax, num_total)
+
+    def one(chunk):
+        domain = pol.to_domain(chunk.astype(jnp.float32), ctx)
+        carry = merge_carry_across(pol, local_carry(domain), axes)
+        return pol.finalize(carry, ctx) / num_total
+
+    if rows.shape[0] == 1:
+        return one(rows[0])[None]
+    return jax.lax.map(one, rows)
 
 
 def collective_mean_tree(grads, residuals, axis_names, *,

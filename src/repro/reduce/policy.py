@@ -304,7 +304,7 @@ class Policy:
     @property
     def carry_dtypes(self) -> Tuple:
         """dtype of each carry component; uniform ``acc_dtype`` unless a
-        policy mixes domains (exact2: int32 limbs + f32 residual pair)."""
+        policy overrides it."""
         return (self.acc_dtype,) * self.carry_len
 
     def domain_width(self, d: int) -> int:
@@ -586,17 +586,17 @@ class Exact2Policy(Policy):
     The scale is sized by magnitude alone (``QBITS`` bits below int32, so
     a 512-row block contribution cannot overflow), each block's int32
     contribution splits into (hi, lo) limbs on the way into the carry,
-    and the third limb carries what quantization rounded away — the
-    per-element residual ``x - descale(quantize(x, scale), scale)``,
-    captured *exactly* (Dekker/Sterbenz; see ``core.intac.limb_split3``)
-    in ``prepare`` and immediately re-split into
+    and the third limb carries what quantization rounded away: the
+    per-element residual, captured exactly and re-split into
     ``intac.RES_NUM_BINS`` integer digits of the quantum-anchored
     ``intac.RES_BIN_BITS`` superaccumulator window (Neal, arXiv
-    1505.05571; the same bin machinery as the procrastinate tier).  All
-    three limbs are then associatively-added int32 state: one exact
-    plane dot per block, up to 2^24 rows carry-free, and ``finalize`` is one
-    ``limbs_resolve3_binned`` — a pure function of the canonical integer
-    totals and the scale.
+    1505.05571; the same bin machinery as the procrastinate tier), all in
+    ``map_rows``.  All three limbs are then associatively-added int32
+    state: one exact plane dot per block, up to 2^24 rows carry-free, and
+    ``finalize`` is one ``limbs_resolve3_binned`` — a pure function of
+    the canonical integer totals and the scale.  This class is the one
+    implementation of the tier: ``reduce`` on every backend,
+    ``elastic_reduce_mean`` and ``collective_mean`` all run it.
 
     Guarantee: the finalized float — not merely the hi/lo limbs — is
     bitwise independent of block size, backend, shard count, mesh shape,
@@ -643,10 +643,6 @@ class Exact2Policy(Policy):
     #: domain layout: [q | digit bin 0 | ... | digit bin RES_NUM_BINS-1]
     _PARTS = 1 + intac.RES_NUM_BINS
 
-    @property
-    def carry_dtypes(self):
-        return (jnp.int32,) * self.carry_len
-
     def domain_width(self, d: int) -> int:
         return self._PARTS * d
 
@@ -654,8 +650,8 @@ class Exact2Policy(Policy):
         if num_terms > self.MAX_TERMS:
             raise ValueError(
                 f"exact2: {num_terms} rows exceed the two-limb headroom "
-                f"bound ({self.MAX_TERMS}); split the stream and merge "
-                f"with core.intac.limb_merge3")
+                f"bound ({self.MAX_TERMS}); split the stream and add the "
+                f"carries")
         return choose_scale(max_abs, 1, qbits=self.QBITS)
 
     def domain_args(self, ctx):
@@ -667,6 +663,16 @@ class Exact2Policy(Policy):
         return (scale,) + intac.ldexp2_factors(-e)
 
     def map_rows(self, values: jnp.ndarray, scale, inv_a, inv_b):
+        """Rows -> [q | residual digits], losslessly.
+
+        ``q = quantize(x, scale)``; the residual ``x - q / scale`` is what
+        the rounding dropped, and it is exact in f32: the scale is a power
+        of two, so ``x * scale`` is exact, ``q / scale`` is x rounded to
+        the scale's grid, and the difference of two so-close values is
+        exact (Sterbenz; Dekker's split).  (q, residual) thus represents x
+        with no loss, and the residual, in quantum units, splits into
+        integer digits with nothing lost above the 49-bit window.
+        """
         v = values.astype(jnp.float32)
         q = quantize(v, scale)
         # ``dequantize(q, scale)`` as its two power-of-two steps.  The

@@ -169,76 +169,6 @@ def test_limb_resolve_is_decomposition_independent():
     assert float(a) == float(b)
 
 
-def test_limb_split3_is_lossless():
-    """The three-limb split loses nothing: x == (hi*2^15 + lo)/scale + r
-    holds *exactly* in f64 (the residual capture is exact Dekker/Sterbenz
-    arithmetic), even for values far off the scale's dyadic grid."""
-    scale = np.float32(2.0 ** 12)
-    for v in (1 / 3, -2.7182818, 1.0000001, 123.4567, 1e-6, -1e-9, 0.0):
-        x = np.float32(v)
-        hi, lo, r = intac.limb_split3(jnp.float32(x), scale)
-        q = int(hi) * (1 << intac.LIMB_SHIFT) + int(lo)
-        assert np.float64(q) / np.float64(scale) + np.float64(np.float32(r)) \
-            == np.float64(x)
-
-
-def test_limbs_resolve3_decomposition_independent_and_1ulp():
-    """The integer canonicalization makes resolve3 independent of the
-    (hi, lo) decomposition, and the compensated combine lands within 1
-    ulp of the f64 reference even when hi exceeds the f32 mantissa."""
-    scale = jnp.float32(1.0)
-    res = jnp.float32(0.37)
-    a = intac.limbs_resolve3(jnp.int32(1000), jnp.int32(2 ** 26 + 123),
-                             res, scale)
-    hi2, lo2 = (np.int32(v) for v in
-                intac.limbs_canonical(jnp.int32(1000),
-                                      jnp.int32(2 ** 26 + 123)))
-    b = intac.limbs_resolve3(jnp.asarray(hi2), jnp.asarray(lo2), res, scale)
-    assert float(a) == float(b)
-    # hi*2^15 needs >24 bits: the split-and-two_sum combine must not lose
-    # the low-order quanta the naive f32 conversion rounds away
-    hi, lo = jnp.int32(1 << 26), jnp.int32(3)
-    ref = np.float64((1 << 26) * (1 << 15) + 3) + np.float64(0.37)
-    got = float(intac.limbs_resolve3(hi, lo, res, scale))
-    assert abs(got - float(ref)) <= np.spacing(np.float32(ref),
-                                               dtype=np.float32)
-
-
-def test_limb3_accumulate_off_grid_within_1ulp():
-    """Off-grid stream (1/3-ish values): the three-limb path tracks the
-    f64 oracle to 1 ulp where the two-limb path visibly rounds, and the
-    split/merge law holds with bitwise-equal canonical integer limbs."""
-    rng = np.random.RandomState(29)
-    xs = (rng.randn(256, 4) / 3 + np.float32(1 / 3)).astype(np.float32)
-    scale = 2.0 ** 16
-    st = intac.limb3_init((4,), scale)
-    for r in xs:
-        st = intac.limb_add3(st, jnp.asarray(r))
-    ref = np.sum(xs.astype(np.float64), axis=0)
-    out3 = np.asarray(intac.limb3_finalize(st))
-    assert (np.abs(out3 - ref)
-            <= np.spacing(np.abs(ref.astype(np.float32)))).all()
-    st2 = intac.limb_init((4,), scale)
-    for r in xs:
-        st2 = intac.limb_add(st2, jnp.asarray(r))
-    out2 = np.asarray(intac.limb_finalize(st2))
-    assert (np.abs(out2 - ref)
-            > np.spacing(np.abs(ref.astype(np.float32)))).any()
-    # split/merge law
-    a = intac.limb3_init((4,), scale)
-    b = intac.limb3_init((4,), scale)
-    for r in xs[:128]:
-        a = intac.limb_add3(a, jnp.asarray(r))
-    for r in xs[128:]:
-        b = intac.limb_add3(b, jnp.asarray(r))
-    m = intac.limb_merge3(a, b)
-    for u, v in zip(intac.limbs_canonical(m.hi, m.lo),
-                    intac.limbs_canonical(st.hi, st.lo)):
-        assert np.array_equal(np.asarray(u), np.asarray(v))
-    assert (np.abs(np.asarray(intac.limb3_finalize(m)) - ref)
-            <= np.spacing(np.abs(ref.astype(np.float32)))).all()
-
-
 def test_wrap_add_trips_exactly_at_the_int32_edge():
     """The wrap predicate is exact: carries within +/-1 of the int32
     boundary flag iff the two's-complement sum actually wrapped."""
@@ -251,55 +181,6 @@ def test_wrap_add_trips_exactly_at_the_int32_edge():
         assert bool(w) == wraps, (a, b)
         if not wraps:
             assert int(s) == int(a) + int(b)
-
-
-def test_limb_add3_saturation_boundary():
-    """ovf trips exactly when a limb add wraps — a carry landing *at*
-    2^31 - 1 is still canonical and raises no flag."""
-    mx = np.int32(2**31 - 1)
-    z = jnp.zeros((), jnp.float32)
-    scale = jnp.float32(1.0)
-    x = jnp.float32(2.0**15)        # quantizes to hi=1, lo=0
-
-    def state(hi):
-        return intac.Limb3State(jnp.int32(hi), jnp.int32(0), z, z, scale,
-                                jnp.int32(0))
-
-    at_edge = intac.limb_add3(state(mx - 1), x)
-    assert int(at_edge.hi) == int(mx) and int(at_edge.ovf) == 0
-    past = intac.limb_add3(state(mx), x)
-    assert int(past.ovf) == 1       # canonical total is now wrong
-    # a further non-wrapping add keeps (not resets) the count
-    again = intac.limb_add3(past, jnp.float32(1.0))
-    assert int(again.ovf) == 1
-    # None ovf (5-field pre-guard-rail construction) stays disabled
-    legacy = intac.Limb3State(jnp.int32(mx), jnp.int32(0), z, z, scale)
-    assert intac.limb_add3(legacy, x).ovf is None
-
-
-def test_limb_merge3_saturation_boundary():
-    """Merging pools both sides' wrap counts plus any wrap the merge
-    itself causes, and trips only when the canonical sum would wrap."""
-    mx = np.int32(2**31 - 1)
-    z = jnp.zeros((), jnp.float32)
-    scale = jnp.float32(1.0)
-
-    def state(hi, lo=0, ovf=0):
-        o = None if ovf is None else jnp.int32(ovf)
-        return intac.Limb3State(jnp.int32(hi), jnp.int32(lo), z, z, scale, o)
-
-    ok = intac.limb_merge3(state(mx - 1), state(1))
-    assert int(ok.hi) == int(mx) and int(ok.ovf) == 0
-    bad = intac.limb_merge3(state(mx), state(1))
-    assert int(bad.ovf) == 1
-    # both limbs wrap in one merge, on top of prior pooled counts
-    both = intac.limb_merge3(state(mx, mx, ovf=2), state(1, 1, ovf=3))
-    assert int(both.ovf) == 2 + 3 + 2
-    # None on both sides disables tracking; one-sided None counts as zero
-    assert intac.limb_merge3(state(1, ovf=None), state(2, ovf=None)).ovf \
-        is None
-    assert int(intac.limb_merge3(state(mx, ovf=None), state(1, ovf=4)).ovf) \
-        == 5
 
 
 def test_choose_scale_zero_and_nan_streams_are_benign():

@@ -160,12 +160,12 @@ def test_policy_merge_is_the_schedule_split(policy):
 def test_merge_across_accumulator_single_device():
     mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:1]), ("shards",))
     from jax.sharding import PartitionSpec as P
-    acc = R.KahanAccumulator()
+    acc = R.CascadeAccumulator(1)
     x = jnp.asarray([1.5, 2.5])
 
     def f(v):
         st = acc.push(acc.init(v), v)
-        return acc.finalize(R.merge_across(acc, st, mesh.axis_names))
+        return acc.finalize(R.merge_across(acc, st, mesh.axis_names))[0]
 
     out = jax.shard_map(f, mesh=mesh, in_specs=P(), out_specs=P(),
                         check_vma=False)(x)
@@ -253,22 +253,26 @@ for ndev in (1, 2, 8):
     ok = all(np.array_equal(a, np.asarray(b)) for a, b in zip(lbase, lsh))
     print(f"LIMBS {ndev} {int(ok)}")
 
-# BinAccumulator declares merge_is_add: merge_across must take the psum
-# fast path and still match a single-device pass bit for bit
+# ProcrastinatePolicy's carry merges by addition: merge_across must take
+# the fused psum and match one device's pass over all 8 rows bit for bit
 from jax.sharding import PartitionSpec as P
 meshA = Mesh(np.asarray(jax.devices()), ("data",))
 xa = jnp.asarray((np.arange(8 * 4).reshape(8, 4) % 7 - 3) * 0.25,
                  dtype=jnp.float32)
-acc = R.BinAccumulator(8.0)
+polP = R.get_policy("procrastinate")
+ctxP = polP.prepare_ctx(jnp.float32(8.0), 8)
+def carry_of(rows):
+    dom = polP.to_domain(rows, ctxP)
+    return R.get_backend("blocked").run(dom, jnp.zeros(rows.shape[0],
+                                                       jnp.int32), 1,
+                                        policy=polP, block_size=8)
 def accf(shard):
-    st = acc.push(acc.init(shard[0]), shard[0])
-    return acc.finalize(R.merge_across(acc, st, ("data",)))
+    return polP.finalize(polP.merge_across(carry_of(shard), ("data",)),
+                         ctxP)
 got = np.asarray(jax.shard_map(accf, mesh=meshA, in_specs=P("data", None),
                                out_specs=P(), check_vma=False)(xa))
-direct = acc.init(xa[0])
-for row in xa:
-    direct = acc.push(direct, row)
-print(f"BINACC {int(np.array_equal(got, np.asarray(acc.finalize(direct))))}")
+direct = np.asarray(polP.finalize(carry_of(xa), ctxP))
+print(f"BINACC {int(np.array_equal(got, direct))}")
 
 # permutation of shards: swap whole shard-sized row chunks; the bitwise
 # tiers must not notice (associative + commutative integer carries);

@@ -47,17 +47,13 @@ from repro.analysis import walker  # noqa: E402
 DOC_FILES = [REPO / "README.md", *sorted((REPO / "docs").glob("*.md"))]
 
 #: load-bearing public API the docs *must* keep naming (and that must
-#: keep importing): the contract surface of the three-limb exact path.
+#: keep importing): the contract surface of the exact2 tier, whose one
+#: implementation is its policy, run by every face.
 #: A rename that forgets the docs — or drops the symbol — fails CI here.
 REQUIRED_SYMBOLS = [
-    "repro.core.intac.limb_split3",
-    "repro.core.intac.limb_add3",
-    "repro.core.intac.limb_merge3",
-    "repro.core.intac.limbs_resolve3",
+    "repro.reduce.policy.Exact2Policy",
+    "repro.core.intac.limbs_resolve3_binned",
     "repro.core.intac.limbs_canonical",
-    "repro.core.intac.intac_psum3",
-    "repro.core.intac.Limb3State",
-    "repro.reduce.Limb3Accumulator",
     "repro.reduce.collective_mean",
     "repro.reduce.merge_carry_across",
     # the robustness surface (docs/robustness.md): status flags, elastic
